@@ -61,6 +61,8 @@ def main() -> None:
                     help="tiny row counts, no JSON overwrite (CI sanity mode)")
     args, _ = ap.parse_known_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from . import (bench_approx, bench_blocking_fusion, bench_dedup,
                    bench_faults, bench_fig6, bench_fusion,
                    bench_opportunistic, bench_outofcore, bench_reuse,

@@ -12,6 +12,7 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.core import DataFrame, EvalMode, Session, set_session
 from repro.core.approx import progressive_aggregate
 from repro.core.partition import PartitionedFrame
@@ -27,6 +28,7 @@ def timed(label, fn):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1_000_000)
     args = ap.parse_args()

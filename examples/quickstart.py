@@ -10,10 +10,12 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.core import DataFrame, EvalMode, Session, get_dummies, set_session
 
 
 def main():
+    compile_cache.enable()
     set_session(Session(mode=EvalMode.OPPORTUNISTIC))
 
     # In[1] — ingest the scraped comparison chart (products as columns)

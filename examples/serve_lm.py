@@ -10,6 +10,7 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_smoke_config
 from repro.data.tokenizer import HashTokenizer
 from repro.models import build_model
@@ -17,6 +18,7 @@ from repro.serve import Request, ServeEngine
 
 
 def main():
+    compile_cache.enable()
     cfg = get_smoke_config("granite-8b")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -14,6 +14,7 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.data import DataPipeline, PipelineConfig, synthetic_corpus
 from repro.models import build_model
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    compile_cache.enable()
 
     # ~100M params: a narrow yi-6b family member (same block structure)
     cfg = dataclasses.replace(

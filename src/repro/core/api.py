@@ -1042,10 +1042,10 @@ def get_dummies(df: DataFrame, columns: Sequence[str]) -> DataFrame:
         for n, c in cdict.items():
             if n in cols and c.domain.is_coded:
                 table = c.dictionary or ()
-                hot = kops.onehot_encode(c.data, len(table))
+                hot = kops.onehot_encode(c.data, len(table))   # (G, M)
                 for g, val in enumerate(table):
                     out_names.append(f"{n}_{val}")
-                    out_cols.append(Column(hot[:, g].astype(np.int32), Domain.INT,
+                    out_cols.append(Column(hot[g].astype(np.int32), Domain.INT,
                                            c.mask, None))
             else:
                 out_names.append(n)

@@ -18,10 +18,8 @@ Representation (DESIGN.md §3 — hardware adaptation):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -36,16 +34,6 @@ from .dtypes import (
 from .labels import CodedLabels, Labels, RangeLabels, labels_from_values
 
 __all__ = ["Column", "Frame"]
-
-
-@functools.lru_cache(maxsize=None)
-def _host_exec() -> bool:
-    """On the CPU backend a per-column device gather/concat is pure dispatch
-    overhead (~15× the cost of the host memcpy it performs): row takes then
-    run as host numpy views that re-enter the device lazily.  TPU keeps the
-    device path.  Probed lazily so importing the library doesn't force jax
-    backend initialization (users may still select a platform afterwards)."""
-    return jax.default_backend() == "cpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,11 +70,8 @@ class Column:
     def valid_mask(self) -> jnp.ndarray | np.ndarray:
         if self.mask is not None:
             return self.mask
-        if _host_exec():
-            # host ones: allocating on device is a ~50µs dispatch per call on
-            # CPU; consumers promote lazily when a device op needs it
-            return np.ones(self.data.shape[0], dtype=np.bool_)
-        return jnp.ones(self.data.shape[0], dtype=jnp.bool_)
+        # host ones: consumers promote lazily when a device op needs them
+        return np.ones(self.data.shape[0], dtype=np.bool_)
 
     def value_at(self, i: int):
         """Decode a single position (host) without materializing the column."""
@@ -103,25 +88,20 @@ class Column:
         return float(v)
 
     def take(self, idx) -> "Column":
-        if isinstance(self.data, np.ndarray) or _host_exec():
-            # host view: numpy fancy index (CPU jax arrays expose their buffer
-            # to np.asarray at memcpy cost, far below a device dispatch)
-            idx_np = np.asarray(idx)
-            return Column(
-                np.asarray(self.data)[idx_np], self.domain,
-                None if self.mask is None else np.asarray(self.mask)[idx_np],
-                self.dictionary)
-        idx = jnp.asarray(idx)
+        """Row gather as a host numpy view that re-enters the device lazily,
+        on every backend.  Take lengths are data-dependent, and on a device
+        each new length compiles a new XLA program: a sample-sort's per-block
+        gathers and concats build one program per piece, and a 1M-row sort
+        on a TPU v5e did not finish in nine minutes.  The host copy of a
+        device array is cached on the array, so a block is fetched once."""
+        idx_np = np.asarray(idx)
         return Column(
-            jnp.take(self.data, idx, axis=0),
-            self.domain,
-            None if self.mask is None else jnp.take(jnp.asarray(self.mask), idx, axis=0),
-            self.dictionary,
-        )
+            np.asarray(self.data)[idx_np], self.domain,
+            None if self.mask is None else np.asarray(self.mask)[idx_np],
+            self.dictionary)
 
     def filter(self, keep: jnp.ndarray) -> "Column":
-        kept = jnp.asarray(np.nonzero(np.asarray(keep))[0])
-        return self.take(kept)
+        return self.take(np.nonzero(np.asarray(keep))[0])
 
     def astype_storage(self, target: Domain) -> jnp.ndarray:
         """Numeric view of this column in ``target``'s storage dtype.
@@ -390,13 +370,10 @@ class Frame:
 
 
 def _concat_arrays(a, b):
-    """Row-axis concat: on host for the CPU backend or pure host views (a
-    device concatenate is a dispatch per call; zero-copy repartition regroups
-    want a plain memcpy).  A device array on an accelerator backend stays on
-    device — mixed host/device pairs promote the host side up, not down."""
-    if _host_exec() or (isinstance(a, np.ndarray) and isinstance(b, np.ndarray)):
-        return np.concatenate([np.asarray(a), np.asarray(b)])
-    return jnp.concatenate([jnp.asarray(a), jnp.asarray(b)])
+    """Row-axis concat on the host, like :meth:`Column.take`: the output
+    length is data-dependent, so a device concatenate would compile a
+    program per length pair."""
+    return np.concatenate([np.asarray(a), np.asarray(b)])
 
 
 def _set_valid(col: Column, r: int) -> jnp.ndarray | None:

@@ -68,6 +68,7 @@ an equivalence oracle for the block-parallel path.
 from __future__ import annotations
 
 import functools
+import logging
 import os
 import threading
 from typing import Any, Callable, Sequence
@@ -78,7 +79,7 @@ import numpy as np
 
 from . import algebra as alg
 from .dtypes import Domain, common_storage, parse_column, storage_dtype
-from .frame import Column, Frame, _host_exec as _frame_host_exec
+from .frame import Column, Frame
 from .labels import CodedLabels, IntLabels, Labels, RangeLabels, labels_from_values
 from .partition import PartitionedFrame
 from .schedule import (GRID_PREFS, dispatch_blocks, output_row_parts,
@@ -86,9 +87,10 @@ from .schedule import (GRID_PREFS, dispatch_blocks, output_row_parts,
 from .store import as_handle, pinned, resolve
 from ..kernels import ops as kops
 
-__all__ = ["run_node", "eval_expr", "NULL_CODE"]
+__all__ = ["run_node", "eval_expr", "NULL_CODE", "map_jit_counts"]
 
 NULL_CODE = -1
+_log = logging.getLogger(__name__)
 
 
 # =============================================================================
@@ -167,8 +169,6 @@ def _has_wide_lit(expr: alg.Expr) -> bool:
 
 def eval_expr(expr: alg.Expr, frame: Frame) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Vectorized evaluation → (values, valid_mask) device arrays."""
-    host = _frame_host_exec()
-
     def getcol(name):
         data, mask, _ = _col_values(frame, name)
         return data, mask
@@ -183,8 +183,7 @@ def eval_expr(expr: alg.Expr, frame: Frame) -> tuple[jnp.ndarray, jnp.ndarray]:
                 return v, c.valid_mask()
         return None
 
-    return _eval_expr_core(expr, getcol, frame.nrows, bin_hook,
-                           _host_full if host else jnp.full)
+    return _eval_expr_core(expr, getcol, frame.nrows, bin_hook, _host_full)
 
 
 def _lit_to_code(column: Column, value: Any) -> int:
@@ -412,7 +411,8 @@ def _row_keys(frame: Frame, subset: Sequence[Any] | None,
             table = c.dictionary or ()
             lut = np.asarray([float(_fnv64(str(v)) & _HASH_MASK) for v in table]
                              or [0.0], dtype=np.float64)
-            codes = np.asarray(c.data)
+            # integer cast: a 0-row coded column may carry float storage
+            codes = np.asarray(c.data).astype(np.int64, copy=False)
             v = lut[np.clip(codes, 0, len(lut) - 1)]
             v = np.where(codes >= 0, v, np.nan)
         elif wide is not None and bool(wide[i]):
@@ -436,7 +436,7 @@ def _sort_rank_keys(frame: Frame, subset: Sequence[Any]) -> list[np.ndarray]:
             rank = np.empty(max(len(table), 1), dtype=np.float64)
             for r, idx in enumerate(sorted(range(len(table)), key=lambda i: str(table[i]))):
                 rank[idx] = r
-            codes = np.asarray(c.data)
+            codes = np.asarray(c.data).astype(np.int64, copy=False)
             v = rank[np.clip(codes, 0, len(table) - 1 if table else 0)]
             v = np.where(codes >= 0, v, np.nan)
         else:
@@ -1879,6 +1879,15 @@ _MAP_JIT_MAX = 128
 _MAP_JIT_MISS = object()
 
 
+def map_jit_counts() -> dict[str, int]:
+    """Map chains currently cached as one traced program (``adopted``) and as
+    eager per-stage dispatch after a failed or divergent probe
+    (``fell_back``).  Each fallback's cause is logged at INFO level."""
+    with _MAP_JIT_LOCK:
+        adopted = sum(e is not None for e in _MAP_JIT.values())
+        return {"adopted": adopted, "fell_back": len(_MAP_JIT) - adopted}
+
+
 def _run_map_stages_eager(frame: Frame, udfs: Sequence[alg.Udf]) -> Frame:
     cur = frame
     for u in udfs:
@@ -1987,7 +1996,12 @@ def _run_map_stages(frame: Frame, udfs: Sequence[alg.Udf]) -> Frame:
         traced = Frame(cols, f.row_labels, labels_from_values(meta["names"]))
         if _frames_bit_equal(eager, traced):
             entry = (fn, meta)
-    except Exception:
+        else:
+            _log.info("map chain %s: traced result differs from eager; "
+                      "kept eager", key[0])
+    except Exception:   # any trace failure: the chain stays eager
+        _log.info("map chain %s failed to trace; kept eager", key[0],
+                  exc_info=True)
         entry = None
     with _MAP_JIT_LOCK:
         while len(_MAP_JIT) >= _MAP_JIT_MAX:

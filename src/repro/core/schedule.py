@@ -64,6 +64,10 @@ Environment knobs (the one table — referenced from ROADMAP.md)
 ``REPRO_JIT_UDFS``         ``1`` forces jit-traced map-stage runs, ``0``
                            forces eager; default: eager on CPU, traced on
                            accelerators (``physical._jit_udfs_enabled``)
+``REPRO_USE_KERNELS``      off-TPU only: ``1`` runs the Pallas kernels in
+                           interpret mode instead of the pure-jnp references
+                           (``kernels.ops.use_pallas``); TPU always runs the
+                           kernels
 ``REPRO_BLOCK_DEDUP``      ``0`` routes DIFFERENCE / DROP-DUPLICATES through
                            the serial whole-frame seed path (baseline /
                            equivalence oracle; ``physical``)

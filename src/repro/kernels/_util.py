@@ -7,7 +7,6 @@ in Python.  ``use_interpret()`` flips automatically on non-TPU backends.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,10 +19,8 @@ SUBLANE = 8     # second-to-last dim granularity for f32
 
 @functools.lru_cache(maxsize=None)
 def use_interpret() -> bool:
-    """Pallas interpret mode: forced via env, or implied off-TPU."""
-    forced = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if forced is not None:
-        return forced not in ("0", "false", "False")
+    """Pallas interpret mode on every backend but TPU, where the kernels
+    always compile through Mosaic."""
     return jax.default_backend() != "tpu"
 
 

@@ -27,7 +27,6 @@ def _transpose_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].T
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn"))
 def _transpose_padded(x: jnp.ndarray, tm: int, tn: int) -> jnp.ndarray:
     m, n = x.shape
     grid = (cdiv(m, tm), cdiv(n, tn))
@@ -41,6 +40,7 @@ def _transpose_padded(x: jnp.ndarray, tm: int, tn: int) -> jnp.ndarray:
     )(x)
 
 
+@functools.partial(jax.jit, static_argnames=("tile_m", "tile_n"))
 def block_transpose(x: jnp.ndarray, *, tile_m: int = 256, tile_n: int = 256) -> jnp.ndarray:
     """Transpose a 2-D array with MXU/VPU-aligned VMEM tiles."""
     assert x.ndim == 2, x.shape

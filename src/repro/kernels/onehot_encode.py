@@ -1,10 +1,15 @@
 """One-hot encoding kernel (paper §2 A1 — ``get_dummies``).
 
-Categorical codes (M,) → indicator matrix (M, G) f32, built tile-by-tile with
-a broadcasted-iota compare so the one-hot never round-trips through HBM as
-int8 gather indices.  Code -1 (null) yields an all-zero row.
+Categorical codes (M,) → class-major indicator matrix (G, M) f32, built
+tile-by-tile with a broadcasted-iota compare so the one-hot never
+round-trips through HBM as int8 gather indices.  Code -1 (null) yields an
+all-zero column.
 
-Grid: (M/TM, G/TG); each program writes one (TM, TG) output tile.
+Rows run along the 128-wide lane axis (codes arrive as (1, M)), so the codes
+and each class's indicator row are lane-dense in HBM; an (M, 1) code column
+or an (M, G) output with few classes would be padded to 128 lanes.
+
+Grid: (M/TM, G/TG); each program writes one (TG, TM) output tile.
 """
 from __future__ import annotations
 
@@ -19,34 +24,33 @@ from ._util import LANE, SUBLANE, cdiv, ceil_to, pad_axis, pick_tile, use_interp
 
 def _onehot_kernel(c_ref, o_ref, *, tg: int):
     j = pl.program_id(1)
-    codes = c_ref[...]                       # (TM, 1) int32
-    local = codes - j * tg
-    seg = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], tg), 1)
-    o_ref[...] = (local == seg).astype(o_ref.dtype)
+    codes = c_ref[...]                       # (1, TM) int32
+    seg = jax.lax.broadcasted_iota(jnp.int32, (tg, codes.shape[1]), 0) + j * tg
+    o_ref[...] = (seg == codes).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("num_classes", "tm", "tg"))
 def _onehot_padded(codes, num_classes: int, tm: int, tg: int):
-    m = codes.shape[0]
+    m = codes.shape[1]
     return pl.pallas_call(
         functools.partial(_onehot_kernel, tg=tg),
         grid=(cdiv(m, tm), cdiv(num_classes, tg)),
-        in_specs=[pl.BlockSpec((tm, 1), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((tm, tg), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, num_classes), jnp.float32),
+        in_specs=[pl.BlockSpec((1, tm), lambda i, j: (0, i))],
+        out_specs=pl.BlockSpec((tg, tm), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((num_classes, m), jnp.float32),
         interpret=use_interpret(),
     )(codes)
 
 
+@functools.partial(jax.jit, static_argnames=("num_classes", "tile_m", "tile_g"))
 def onehot_encode(codes: jnp.ndarray, num_classes: int, *,
-                  tile_m: int = 512, tile_g: int = 512) -> jnp.ndarray:
-    """(M,) int32 codes → (M, num_classes) f32 one-hot (−1 → zero row)."""
+                  tile_m: int = 2048, tile_g: int = 64) -> jnp.ndarray:
+    """(M,) int32 codes → (num_classes, M) f32 one-hot (−1 → zero column)."""
     assert codes.ndim == 1
     m = codes.shape[0]
     if m == 0:
-        return jnp.zeros((0, num_classes), jnp.float32)
-    tm = pick_tile(m, tile_m, SUBLANE)
-    tg = pick_tile(num_classes, tile_g, LANE)
-    cp = pad_axis(codes.astype(jnp.int32)[:, None], 0, ceil_to(m, tm), value=-1)
+        return jnp.zeros((num_classes, 0), jnp.float32)
+    tm = pick_tile(m, tile_m, LANE)
+    tg = pick_tile(num_classes, tile_g, SUBLANE)
+    cp = pad_axis(codes.astype(jnp.int32)[None, :], 1, ceil_to(m, tm), value=-1)
     out = _onehot_padded(cp, ceil_to(num_classes, tg), tm, tg)
-    return out[:m, :num_classes]
+    return out[:num_classes, :m]

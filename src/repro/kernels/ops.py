@@ -1,11 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Dispatch policy:
-  * On TPU — always the Pallas kernels.
-  * On CPU — the pure-jnp references by default (XLA:CPU fuses them well and
-    the interpret-mode emulation is for *validation*, not speed); set
-    ``REPRO_USE_KERNELS=1`` to force the kernels (interpret=True) anywhere,
-    ``REPRO_FORCE_REF=1`` to force the references anywhere.
+  * On TPU — always the Pallas kernels, compiled by Mosaic; no environment
+    variable can swap in the references or interpret mode there.
+  * Elsewhere — the pure-jnp references by default (XLA:CPU fuses them well
+    and the interpret-mode emulation is for *validation*, not speed); set
+    ``REPRO_USE_KERNELS=1`` to run the kernels in interpret mode instead.
 
 Every wrapper has an identically-shaped oracle in ``ref.py``; tests sweep
 shapes × dtypes asserting allclose between the two.
@@ -36,11 +36,9 @@ __all__ = [
 
 
 def use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_REF", "0") not in ("0", ""):
-        return False
-    if os.environ.get("REPRO_USE_KERNELS", "0") not in ("0", ""):
+    if jax.default_backend() == "tpu":
         return True
-    return jax.default_backend() == "tpu"
+    return os.environ.get("REPRO_USE_KERNELS", "0") not in ("0", "")
 
 
 # -----------------------------------------------------------------------------

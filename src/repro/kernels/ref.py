@@ -2,7 +2,7 @@
 
 Tests sweep shapes × dtypes and ``assert_allclose`` each kernel (run with
 ``interpret=True`` on CPU) against these references.  The references are also
-the fallback execution path (``REPRO_FORCE_REF=1``).
+the execution path off-TPU (``ops.use_pallas``).
 """
 from __future__ import annotations
 
@@ -81,9 +81,10 @@ def linear_scan(a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray | None = None) -
 
 # -----------------------------------------------------------------------------
 def onehot_encode(codes: jnp.ndarray, num_classes: int) -> jnp.ndarray:
-    """Oracle for onehot_encode: (M,) int32 → (M, G) f32; code -1 → all-zero."""
-    eye = jax.nn.one_hot(jnp.where(codes >= 0, codes, num_classes), num_classes + 1)
-    return eye[:, :num_classes].astype(jnp.float32)
+    """Oracle for onehot_encode: (M,) int32 → class-major (G, M) f32; code -1
+    → all-zero column."""
+    classes = jnp.arange(num_classes, dtype=jnp.int32)[:, None]
+    return (classes == codes[None, :]).astype(jnp.float32)
 
 
 # -----------------------------------------------------------------------------

@@ -282,8 +282,6 @@ def run_pipeline_cell(multi_pod: bool, rows: int = 1 << 22, cols: int = 256,
     """Lower the Fig.-6 operator mix (map + groupby(n) + groupby(1) + window)
     as one shard_map program over the production mesh: rows shard DP, columns
     shard "model"; the groupby combine is the psum the paper's shuffle became."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
     dp = dp_axes(mesh)
@@ -292,11 +290,11 @@ def run_pipeline_cell(multi_pod: bool, rows: int = 1 << 22, cols: int = 256,
     codes = jax.ShapeDtypeStruct((rows,), jnp.int32)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(dp, "model"), P(dp)),
         out_specs=(P(dp, "model"), P(None, "model"), P(None, "model"),
                    P(dp, "model")),
-        check_rep=False)
+        check_vma=False)
     def pipeline_step(v, c):
         # MAP: null-scrub (paper's map benchmark: isnull→fill)
         mapped = jnp.where(jnp.isnan(v), 0.0, v)

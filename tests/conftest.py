@@ -84,4 +84,3 @@ def lazy_session():
 def use_pallas_kernels(monkeypatch):
     """Force the Pallas kernels (interpret mode on CPU) for this test."""
     monkeypatch.setenv("REPRO_USE_KERNELS", "1")
-    monkeypatch.delenv("REPRO_FORCE_REF", raising=False)

@@ -34,16 +34,17 @@ def test_segment_reduce(rng, m, g, op):
 
 
 @pytest.mark.parametrize("cols", [1, 3, 7])
-def test_segment_reduce_multicolumn(rng, cols):
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+def test_segment_reduce_multicolumn(rng, cols, op):
     m, g = 777, 13
     v = jnp.asarray(rng.standard_normal((m, cols)).astype(np.float32))
-    c = jnp.asarray(rng.integers(0, g, m).astype(np.int32))
-    np.testing.assert_allclose(np.asarray(segment_reduce(v, c, g, "sum")),
-                               np.asarray(ref.segment_reduce(v, c, g, "sum")),
+    c = jnp.asarray(rng.integers(-1, g, m).astype(np.int32))
+    np.testing.assert_allclose(np.asarray(segment_reduce(v, c, g, op)),
+                               np.asarray(ref.segment_reduce(v, c, g, op)),
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("m,n", [(64, 1), (1000, 5), (2049, 3)])
+@pytest.mark.parametrize("m,n", [(64, 1), (1000, 5), (2049, 3), (70000, 2)])
 @pytest.mark.parametrize("op", ["cumsum", "cummax", "cummin"])
 def test_window_scan(rng, m, n, op):
     x = jnp.asarray(rng.standard_normal((m, n)).astype(np.float32))
