@@ -62,8 +62,6 @@ MAP_ULPS = 2
 # Everything else — ints, keys, counts, min/max, orders, copied values — must
 # match exactly.
 
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT = "/jax/compilation_cache/cache_hits"
 # the jitted programs of repro.kernels.ops that the engine calls around its
 # Pallas kernels: groupby partials, cumsum/cummax, get_dummies, transpose
 KERNELS = ("_segment_reduce_multi_prog", "_pallas_winscan", "_pallas_onehot",
@@ -71,30 +69,6 @@ KERNELS = ("_segment_reduce_multi_prog", "_pallas_winscan", "_pallas_onehot",
 
 
 # ---------------------------------------------------------------------------
-class CompileLog:
-    """Counts XLA programs built in this process (``programs``) and how many
-    of them came from the persistent cache (``hits``)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.programs = 0
-        self.hits = 0
-
-    def on_duration(self, event, duration, **kw):
-        if event == BACKEND_COMPILE:
-            with self._lock:
-                self.programs += 1
-
-    def on_event(self, event, **kw):
-        if event == CACHE_HIT:
-            with self._lock:
-                self.hits += 1
-
-    def snapshot(self) -> tuple[int, int]:
-        with self._lock:
-            return self.programs, self.hits
-
-
 class KernelCalls:
     """Wraps the ``KERNELS`` programs of ``repro.kernels.ops`` for the run:
     counts the engine's calls to each and keeps the abstract arguments of its
@@ -556,10 +530,8 @@ def main() -> int:
         return 1
 
     cache_dir = compile_cache.enable()
-    log = CompileLog()
+    compile_cache.listen()
     kernels = KernelCalls()
-    jax.monitoring.register_event_duration_secs_listener(log.on_duration)
-    jax.monitoring.register_event_listener(log.on_event)
     logging.basicConfig(level=logging.WARNING)
     logging.getLogger("repro.core.physical").setLevel(logging.INFO)
     print(f"rows: {args.rows}  csv rows: {max(1, args.rows // 10)}  seed: {args.seed}  "
@@ -575,12 +547,12 @@ def main() -> int:
     failed = []
     with tempfile.TemporaryDirectory() as tmpdir:
         for name, run, check in phases(s, df, pdf, dim_df, dim, args, tmpdir):
-            p0, h0 = log.snapshot()
+            p0, h0 = compile_cache.counts()
             t = time.perf_counter()
             try:
                 result = run()
                 wall = time.perf_counter() - t
-                p1, h1 = log.snapshot()
+                p1, h1 = compile_cache.counts()
                 programs, hits = p1 - p0, h1 - h0
                 kind = "warm" if programs == hits else "cold"
                 detail = check(result)
@@ -607,7 +579,7 @@ def main() -> int:
     if "peak_bytes_in_use" in mem:
         print(f"device memory: peak {mem['peak_bytes_in_use'] / 2**30:.2f} GiB "
               f"of {mem.get('bytes_limit', 0) / 2**30:.2f} GiB")
-    programs, hits = log.snapshot()
+    programs, hits = compile_cache.counts()
     print(f"total: {programs} programs ({programs - hits} compiled, "
           f"{hits} persistent-cache hits) in {time.perf_counter() - t0:.1f} s")
 

@@ -26,6 +26,7 @@ from .frame import Column, Frame
 from .labels import RangeLabels, labels_from_values
 from .partition import PartitionedFrame
 from .session import EvalMode, Session, get_session
+from .transfer import to_host
 from ..kernels import ops as kops
 
 __all__ = ["DataFrame", "read_csv", "from_pydict", "concat", "get_dummies"]
@@ -485,7 +486,8 @@ def _expr_assign_fn(key: str, expr: alg.Expr):
         dom = (Domain.BOOL if v.dtype == jnp.bool_
                else Domain.INT if jnp.issubdtype(v.dtype, jnp.integer) else Domain.FLOAT)
         out = dict(cdict)
-        out[key] = Column(v, dom, None if bool(mask.all()) else mask, None)
+        out[key] = Column(v, dom, None if bool(to_host(mask.all())) else mask,
+                          None)
         return Frame(list(out.values()), frame.row_labels,
                      labels_from_values(list(out.keys())))
 

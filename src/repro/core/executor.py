@@ -34,6 +34,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from .. import compile_cache
 from . import algebra as alg
 from . import physical, rewrite
 from .frame import Frame
@@ -45,7 +46,8 @@ from .faults import ExecutorClosedError, StatementCancelled
 from .schedule import node_scope, stats_scope
 from .store import get_store
 
-__all__ = ["Executor", "CacheEntry", "ExecStats", "StatsTee"]
+__all__ = ["Executor", "CacheEntry", "ExecStats", "StatsTee",
+           "SCOPE_COUNTERS"]
 
 
 @dataclass
@@ -186,6 +188,29 @@ class ExecStats:
                                     (queue_wait + slot_hold ≈ the tenant's
                                     pool pressure: ``QueryService.
                                     tenant_report`` ranks sessions by these).
+
+    Transfer and compile counters — always on (int adds), bumped through
+    the node's stats scope (``schedule.count``), on the caller thread or a
+    pool thread, so they land here, in the tenant's stats and in the node's
+    span (``SCOPE_COUNTERS``):
+
+      * ``d2h_bytes``             — bytes copied device→host
+                                    (``transfer.to_host``: row takes,
+                                    concats, key and mask reads, scalar
+                                    reads).  A device array's host copy is
+                                    cached, so only its first read counts;
+      * ``d2h_copies``            — those copies: the points where the host
+                                    blocked on a device value;
+      * ``h2d_bytes``             — bytes of host arrays that entered a
+                                    device program (kernel entry points,
+                                    jitted predicates and map chains, mixed
+                                    host/device expression operands,
+                                    explicit ``jnp.asarray``), each array
+                                    once per entry;
+      * ``compiles``              — XLA programs built while a node ran,
+                                    compiled or loaded from the persistent
+                                    cache (``compile_cache.listen``);
+      * ``compile_ns``            — the time those builds took.
     """
 
     evaluated_nodes: int = 0
@@ -221,11 +246,21 @@ class ExecStats:
     plan_prep_ns: int = 0
     queue_wait_ns: int = 0
     slot_hold_ns: int = 0
+    d2h_bytes: int = 0
+    d2h_copies: int = 0
+    h2d_bytes: int = 0
+    compiles: int = 0
+    compile_ns: int = 0
 
     @property
     def blocks_per_dispatch(self) -> float:
         return self.dispatched_blocks / max(1, self.dispatches)
 
+
+# the ExecStats counters bumped through the node's stats scope: a traced
+# node's span carries its own delta of each, tallied as they are bumped
+SCOPE_COUNTERS = ("d2h_bytes", "d2h_copies", "h2d_bytes", "compiles",
+                  "compile_ns")
 
 _TEE_LOCK = threading.Lock()
 
@@ -298,6 +333,7 @@ class Executor:
         self._opt_memo: dict[tuple, alg.Node] = {}
         self._bg = _fut.ThreadPoolExecutor(max_workers=background_workers,
                                            thread_name_prefix="repro-bg")
+        compile_cache.listen()
 
     def _stats(self) -> Any:
         """Stats sink for the calling context: the executor's global counters,
@@ -558,11 +594,19 @@ class Executor:
                     # this span's duration and counter delta are exactly this
                     # node's own work — per-statement spans partition the
                     # statement's ExecStats movement
+                    # the scope tees into a tally of this node alone, so
+                    # its span carries exactly what was bumped under it,
+                    # on whatever thread
+                    tally = ExecStats()
+                    tee = StatsTee(*(st._targets if isinstance(st, StatsTee)
+                                     else (st,)), tally)
                     with tr.span(f"eval:{node.op}", "node") as span:
-                        with stats_scope(st), node_scope(node.op):
-                            result = physical.run_node(node, inputs, st)
+                        with stats_scope(tee), node_scope(node.op):
+                            result = physical.run_node(node, inputs, tee)
                         span.args = self._attribute_store_delta(
                             s0, f0, want_delta=True)
+                        span.args.update((k, getattr(tally, k))
+                                         for k in SCOPE_COUNTERS)
                 st.node_wall_ns += time.perf_counter_ns() - tn0
             dt = time.monotonic() - t0
             st.evaluated_nodes += 1

@@ -32,6 +32,7 @@ from .dtypes import (
     storage_dtype,
 )
 from .labels import CodedLabels, Labels, RangeLabels, labels_from_values
+from .transfer import to_device, to_host
 
 __all__ = ["Column", "Frame"]
 
@@ -50,8 +51,8 @@ class Column:
 
     # ---- host materialization -------------------------------------------
     def to_pylist(self) -> list:
-        data = np.asarray(self.data)
-        mask = np.asarray(self.mask) if self.mask is not None else None
+        data = to_host(self.data)
+        mask = to_host(self.mask) if self.mask is not None else None
         out: list = []
         for i in range(data.shape[0]):
             if mask is not None and not mask[i]:
@@ -75,9 +76,9 @@ class Column:
 
     def value_at(self, i: int):
         """Decode a single position (host) without materializing the column."""
-        if self.mask is not None and not bool(self.mask[i]):
+        if self.mask is not None and not bool(to_host(self.mask[i])):
             return None
-        v = self.data[i]
+        v = to_host(self.data[i])
         if self.domain.is_coded:
             code = int(v)
             return self.dictionary[code] if 0 <= code < len(self.dictionary) else None
@@ -93,15 +94,16 @@ class Column:
         each new length compiles a new XLA program: a sample-sort's per-block
         gathers and concats build one program per piece, and a 1M-row sort
         on a TPU v5e did not finish in nine minutes.  The host copy of a
-        device array is cached on the array, so a block is fetched once."""
-        idx_np = np.asarray(idx)
+        device array is cached on the array, so a block is fetched once
+        (and counted once, ``transfer.to_host``)."""
+        idx_np = to_host(idx)
         return Column(
-            np.asarray(self.data)[idx_np], self.domain,
-            None if self.mask is None else np.asarray(self.mask)[idx_np],
+            to_host(self.data)[idx_np], self.domain,
+            None if self.mask is None else to_host(self.mask)[idx_np],
             self.dictionary)
 
     def filter(self, keep: jnp.ndarray) -> "Column":
-        return self.take(np.nonzero(np.asarray(keep))[0])
+        return self.take(np.nonzero(to_host(keep))[0])
 
     def astype_storage(self, target: Domain) -> jnp.ndarray:
         """Numeric view of this column in ``target``'s storage dtype.
@@ -171,7 +173,7 @@ class Frame:
         Wide-frame fast path: one host materialization + numpy column views
         (per-column device slices would cost O(n) dispatches)."""
         m, n = values.shape
-        host = np.asarray(values).astype(storage_dtype(domain), copy=False)
+        host = to_host(values).astype(storage_dtype(domain), copy=False)
         cols = [Column(host[:, j], domain) for j in range(n)]
         return Frame(
             cols,
@@ -240,15 +242,15 @@ class Frame:
             return jnp.zeros((f.nrows, 0), storage_dtype(tgt)), tgt
         # stack on host (O(1) per column, no per-column device dispatch —
         # matters for post-transpose frames with 10⁵⁺ columns)
-        mat_np = np.stack([np.asarray(c.astype_storage(tgt)) for c in f.columns],
+        mat_np = np.stack([to_host(c.astype_storage(tgt)) for c in f.columns],
                           axis=1)
-        return jnp.asarray(mat_np), tgt
+        return to_device(mat_np), tgt
 
     # ------------------------------------------------------------------
     # row/column selection
     # ------------------------------------------------------------------
     def take_rows(self, idx) -> "Frame":
-        idx_np = np.asarray(idx)
+        idx_np = to_host(idx)
         rd = None
         if self.row_domains is not None and len(self.row_domains) == self.nrows:
             rd = tuple(self.row_domains[int(i)] for i in idx_np)
@@ -260,7 +262,7 @@ class Frame:
         )
 
     def filter_rows(self, keep: np.ndarray) -> "Frame":
-        idx = np.nonzero(np.asarray(keep))[0]
+        idx = np.nonzero(to_host(keep))[0]
         return self.take_rows(idx)
 
     def take_cols(self, idx: Sequence[int]) -> "Frame":
@@ -328,10 +330,10 @@ class Frame:
             if key not in table:
                 table.append(key)
             code = table.index(key)
-            data = jnp.asarray(col.data).at[r].set(np.int32(code))
+            data = to_device(col.data).at[r].set(np.int32(code))
             new = Column(data, col.domain, _set_valid(col, r), tuple(table))
         else:
-            data = jnp.asarray(col.data).at[r].set(
+            data = to_device(col.data).at[r].set(
                 np.asarray(value, dtype=col.data.dtype))
             new = Column(data, col.domain, _set_valid(col, r), None)
         cols = list(self.columns)
@@ -373,13 +375,13 @@ def _concat_arrays(a, b):
     """Row-axis concat on the host, like :meth:`Column.take`: the output
     length is data-dependent, so a device concatenate would compile a
     program per length pair."""
-    return np.concatenate([np.asarray(a), np.asarray(b)])
+    return np.concatenate([to_host(a), to_host(b)])
 
 
 def _set_valid(col: Column, r: int) -> jnp.ndarray | None:
     if col.mask is None:
         return None
-    return jnp.asarray(col.mask).at[r].set(True)
+    return to_device(col.mask).at[r].set(True)
 
 
 def _unify_pair(a: Column, b: Column) -> tuple[Column, Column]:
@@ -406,9 +408,9 @@ def _unify_pair(a: Column, b: Column) -> tuple[Column, Column]:
             codes_b[i] = index[key]
         ca = Column(pa.data, Domain.STR, pa.mask, tuple(table))
         cb = Column(
-            jnp.asarray(codes_b),
+            to_device(codes_b),
             Domain.STR,
-            jnp.asarray(mask_b) if not mask_b.all() else None,
+            to_device(mask_b) if not mask_b.all() else None,
             tuple(table),
         )
         return ca, cb

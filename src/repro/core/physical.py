@@ -85,6 +85,8 @@ from .partition import PartitionedFrame
 from .schedule import (GRID_PREFS, dispatch_blocks, output_row_parts,
                        preferred_row_parts)
 from .store import as_handle, pinned, resolve
+from .trace import phase
+from .transfer import note_h2d, to_device, to_host
 from ..kernels import ops as kops
 
 __all__ = ["run_node", "eval_expr", "NULL_CODE", "map_jit_counts"]
@@ -206,16 +208,19 @@ def _bin_numeric(op: str, lv, lm, rv, rm) -> tuple[jnp.ndarray, jnp.ndarray]:
     dtypes, ``+ - *`` wrap on int32 overflow; ``% //`` by zero yield null.
     A wide int64 host operand pins the pair to host numpy (a mixed np/jax op
     would canonicalize the wide side through int32 and truncate)."""
+    _note_mixed(lm, rm)
     mask = lm & rm
     if op in ("&", "|"):
+        _note_mixed(lv, rv)
         lb, rb = lv.astype(jnp.bool_), rv.astype(jnp.bool_)
         return (lb & rb if op == "&" else lb | rb), mask
     both_int = (jnp.issubdtype(lv.dtype, jnp.integer)
                 and jnp.issubdtype(rv.dtype, jnp.integer))
     if both_int and (_wide_host_int(lv) or _wide_host_int(rv)):
-        lv = np.asarray(lv, dtype=np.int64)
-        rv = np.asarray(rv, dtype=np.int64)
+        lv = to_host(lv, np.int64)
+        rv = to_host(rv, np.int64)
     if op in ("+", "-", "*", "%", "//") and both_int:
+        _note_mixed(lv, rv)
         if op == "+":
             return lv + rv, mask
         if op == "-":
@@ -235,6 +240,7 @@ def _bin_numeric(op: str, lv, lm, rv, rm) -> tuple[jnp.ndarray, jnp.ndarray]:
                 else jnp.floor_divide(lv, rv)), mask
     if op in ("+", "-", "*", "/", "%", "//"):
         lf, rf = _as_float_pair(lv, rv)
+        _note_mixed(lf, rf)
         if op in ("%", "//"):
             if isinstance(lf, np.ndarray) and lf.dtype.itemsize > 4:
                 # the wide/f64 pair stays on host numpy end to end (jax mod
@@ -252,6 +258,7 @@ def _bin_numeric(op: str, lv, lm, rv, rm) -> tuple[jnp.ndarray, jnp.ndarray]:
         lf, rf = lv, rv
     else:
         lf, rf = _as_float_pair(lv, rv)
+    _note_mixed(lf, rf)
     out = {
         "==": lf == rf, "!=": lf != rf, "<": lf < rf,
         "<=": lf <= rf, ">": lf > rf, ">=": lf >= rf,
@@ -271,16 +278,24 @@ def _as_float_pair(lv, rv):
     except AttributeError:
         wide = False
     if wide:
-        return np.asarray(lv, np.float64), np.asarray(rv, np.float64)
+        return to_host(lv, np.float64), to_host(rv, np.float64)
     return lv.astype(jnp.float32), rv.astype(jnp.float32)
+
+
+def _note_mixed(a, b) -> None:
+    """A host operand of an op with a device operand is copied to the
+    device: count it (``h2d_bytes``)."""
+    if isinstance(a, jax.Array) != isinstance(b, jax.Array):
+        note_h2d(a, b)
 
 
 def _predicate_mask(frame: Frame, predicate) -> np.ndarray:
     if isinstance(predicate, alg.Udf):
         out = predicate.fn({n: c for n, c in zip(frame.col_labels.to_list(), frame.columns)}, frame)
-        return np.asarray(out, dtype=bool)
+        return to_host(out, bool)
     v, mask = eval_expr(predicate, frame)
-    return np.asarray(v.astype(jnp.bool_) & mask)  # null comparisons → False
+    _note_mixed(v, mask)
+    return to_host(v.astype(jnp.bool_) & mask)  # null comparisons → False
 
 
 # =============================================================================
@@ -388,9 +403,9 @@ def _wide_int_flags(frame: Frame, subset: Sequence[Any] | None) -> np.ndarray:
         # per-block device→host copy just to skip them
         if c.domain is not Domain.INT or c.data.dtype.itemsize <= 4:
             continue
-        v = np.asarray(c.data)
+        v = to_host(c.data)
         if c.mask is not None:
-            v = v[np.asarray(c.mask)]
+            v = v[to_host(c.mask)]
         if v.size and bool(((v > _WIDE_INT_LIMIT) | (v < -_WIDE_INT_LIMIT)).any()):
             out[i] = True
     return out
@@ -412,15 +427,15 @@ def _row_keys(frame: Frame, subset: Sequence[Any] | None,
             lut = np.asarray([float(_fnv64(str(v)) & _HASH_MASK) for v in table]
                              or [0.0], dtype=np.float64)
             # integer cast: a 0-row coded column may carry float storage
-            codes = np.asarray(c.data).astype(np.int64, copy=False)
+            codes = to_host(c.data).astype(np.int64, copy=False)
             v = lut[np.clip(codes, 0, len(lut) - 1)]
             v = np.where(codes >= 0, v, np.nan)
         elif wide is not None and bool(wide[i]):
-            v = _wide_key_values(np.asarray(c.data))
+            v = _wide_key_values(to_host(c.data))
         else:
-            v = np.asarray(c.data, dtype=np.float64)
+            v = to_host(c.data, np.float64)
         if c.mask is not None:
-            v = np.where(np.asarray(c.mask), v, np.nan)
+            v = np.where(to_host(c.mask), v, np.nan)
         mats.append(v)
     return np.stack(mats, axis=1) if mats else np.zeros((frame.nrows, 0))
 
@@ -436,13 +451,13 @@ def _sort_rank_keys(frame: Frame, subset: Sequence[Any]) -> list[np.ndarray]:
             rank = np.empty(max(len(table), 1), dtype=np.float64)
             for r, idx in enumerate(sorted(range(len(table)), key=lambda i: str(table[i]))):
                 rank[idx] = r
-            codes = np.asarray(c.data).astype(np.int64, copy=False)
+            codes = to_host(c.data).astype(np.int64, copy=False)
             v = rank[np.clip(codes, 0, len(table) - 1 if table else 0)]
             v = np.where(codes >= 0, v, np.nan)
         else:
-            v = np.asarray(c.data, dtype=np.float64)
+            v = to_host(c.data, np.float64)
         if c.mask is not None:
-            v = np.where(np.asarray(c.mask), v, np.nan)
+            v = np.where(to_host(c.mask), v, np.nan)
         out.append(v)
     return out
 
@@ -466,7 +481,8 @@ def _keys_to_ids(*key_mats: np.ndarray) -> list[np.ndarray]:
         # The per-column uniques go through the pool (numpy's sort drops the
         # GIL, so the columns genuinely factorize in parallel).
         def col_inv(j: int):
-            _, invj = np.unique(view[:, j], return_inverse=True)
+            with phase("keys:unique"):
+                _, invj = np.unique(view[:, j], return_inverse=True)
             return (invj.astype(np.int64),
                     int(invj.max()) + 1 if invj.size else 1)
 
@@ -839,7 +855,7 @@ def _gather_join_cols(lf: Frame, rf: Frame, lidx, ridx, lvalid, rvalid,
         else:
             raise KeyError(n)
         if side_valid is not None and not side_valid.all():
-            vm = jnp.asarray(c.valid_mask()) & jnp.asarray(side_valid)
+            vm = to_device(c.valid_mask()) & to_device(side_valid)
             c = Column(c.data, c.domain, vm, c.dictionary)
         cols.append(c)
         out_names.append(n)
@@ -910,7 +926,7 @@ def _assemble_join(lf: Frame, rf: Frame, lidx, ridx, lvalid, rvalid, drop_right,
 def _mask_all(frame: Frame, valid: np.ndarray | None) -> Frame:
     if valid is None or valid.all():
         return frame
-    vmask = jnp.asarray(valid)
+    vmask = to_device(valid)
     cols = [Column(c.data, c.domain, c.valid_mask() & vmask, c.dictionary) for c in frame.columns]
     return Frame(cols, frame.row_labels, frame.col_labels, frame.row_domains)
 
@@ -929,10 +945,11 @@ def _groupby(pf: PartitionedFrame, keys: Sequence[Any], aggs: Sequence[tuple]) -
     the fusion pass records on ``FusedGroupBy`` — blocks ≈ workers), so a
     256-partition frame on a 4-worker pool computes ~8 partials, not 256.
     """
-    rp = preferred_row_parts(pf.row_parts, GRID_PREFS["groupby"],
-                             total_bytes=pf.nbytes())
-    pf = pf.repartition(row_parts=rp, col_parts=1)
-    row_blocks = [row[0].induce() for row in pf.parts]
+    with phase("groupby:regrid"):
+        rp = preferred_row_parts(pf.row_parts, GRID_PREFS["groupby"],
+                                 total_bytes=pf.nbytes())
+        pf = pf.repartition(row_parts=rp, col_parts=1)
+        row_blocks = [row[0].induce() for row in pf.parts]
     return _groupby_blocks(row_blocks, keys, aggs)
 
 
@@ -941,26 +958,40 @@ def _groupby_blocks(row_blocks: list, keys: Sequence[Any],
     # the general factorization needs a global view of every block's keys, so
     # this path materializes all blocks (handles fault here); the fused
     # dense-int path above it is the memory-governed one
-    row_blocks = [resolve(b) for b in row_blocks]
-    # ---- dense small-range INT key: no host factorization ------------------
-    # (paper's groupby(n) benchmark shape: "passenger_count"-like keys).
-    # codes = v - min, computed per block in parallel; empty groups dropped
-    # after the combine.  Avoids the serial np.unique Amdahl term.
-    dense = _dense_int_key(row_blocks, keys) if len(keys) == 1 else None
+    with phase("groupby:resolve"):
+        row_blocks = [resolve(b) for b in row_blocks]
+    with phase("groupby:keys"):
+        dense = _dense_int_key(row_blocks, keys) if len(keys) == 1 else None
+        if dense is None:
+            codes_per_block, G, rep_sorted = _factorize_keys(row_blocks, keys)
+        else:
+            # dense small-range INT key: no host factorization (paper's
+            # groupby(n) benchmark shape: "passenger_count"-like keys).
+            # codes = v - min per block; empty groups dropped after the
+            # combine.  Avoids the serial np.unique Amdahl term.
+            vmin, G = dense
+            codes_per_block = [_dense_codes(b.col(keys[0]), vmin)
+                               for b in row_blocks]
     if dense is not None:
-        vmin, G = dense
-        codes_per_block = []
-        for b in row_blocks:
-            c = b.col(keys[0])
-            codes = np.asarray(c.data, dtype=np.int64) - vmin
-            if c.mask is not None:
-                codes = np.where(np.asarray(c.mask), codes, -1)
-            codes_per_block.append(codes.astype(np.int32))
         return _groupby_with_codes(row_blocks, keys, aggs, codes_per_block,
                                    int(G), key_values=[int(vmin) + i for i in range(int(G))],
                                    drop_empty=True)
+    return _groupby_with_codes(row_blocks, keys, aggs, codes_per_block, G,
+                               rep_sorted=rep_sorted)
 
-    # ---- global key factorization (one column set to host) -----------------
+
+def _dense_codes(c: Column, vmin: int) -> np.ndarray:
+    """Group codes ``v - vmin`` of a dense INT key column, -1 where null."""
+    codes = to_host(c.data, np.int64) - vmin
+    if c.mask is not None:
+        codes = np.where(to_host(c.mask), codes, -1)
+    return codes.astype(np.int32)
+
+
+def _factorize_keys(row_blocks: list, keys: Sequence[Any]):
+    """Global key factorization (one column set to host): per-block group
+    codes in the lexicographic order of the groups' key values, the group
+    count, and each group's representative key values."""
     if keys:
         flags = np.zeros(len(keys), dtype=bool)
         for b in row_blocks:
@@ -996,8 +1027,7 @@ def _groupby_blocks(row_blocks: list, keys: Sequence[Any],
         G = 1
         rep_sorted = None
         codes_per_block = [np.zeros(b.nrows, dtype=np.int32) for b in row_blocks]
-    return _groupby_with_codes(row_blocks, keys, aggs, codes_per_block, G,
-                               rep_sorted=rep_sorted)
+    return codes_per_block, G, rep_sorted
 
 
 def _dense_int_key(row_blocks: list[Frame], keys) -> tuple[int, int] | None:
@@ -1011,9 +1041,9 @@ def _dense_int_key(row_blocks: list[Frame], keys) -> tuple[int, int] | None:
         return None
     vmin, vmax = None, None
     for c in cols:
-        v = np.asarray(c.data, dtype=np.int64)
+        v = to_host(c.data, np.int64)
         if c.mask is not None:
-            mask = np.asarray(c.mask)
+            mask = to_host(c.mask)
             if not mask.any():
                 continue
             v = v[mask]
@@ -1090,9 +1120,12 @@ def _groupby_with_codes(row_blocks: list[Frame], keys, aggs, codes_per_block,
 
     partials = dispatch_blocks(block_partial, list(zip(row_blocks, codes_per_block)))
     want = need + [_PRESENCE] if drop_empty else need
-    combined = _combine_partials(partials, want)
-    return _finalize_groupby(combined, row_blocks[0] if row_blocks else None,
-                             keys, aggs, G, rep_sorted, key_values, drop_empty)
+    with phase("groupby:combine"):
+        combined = _combine_partials(partials, want)
+    with phase("groupby:finalize"):
+        return _finalize_groupby(
+            combined, row_blocks[0] if row_blocks else None, keys, aggs, G,
+            rep_sorted, key_values, drop_empty)
 
 
 def _finalize_groupby(combined: dict, template: Frame | None, keys, aggs,
@@ -1140,7 +1173,7 @@ def _finalize_groupby(combined: dict, template: Frame | None, keys, aggs,
 
     frame = Frame(out_cols, RangeLabels(G), labels_from_values(out_names))
     if drop_empty:
-        present = np.asarray(combined[("__presence__", "sum")]) > 0
+        present = to_host(combined[("__presence__", "sum")]) > 0
         frame = frame.filter_rows(present)
     return _output_pf(frame)
 
@@ -1204,10 +1237,12 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
                 except KeyError:
                     c = None
                 if c is not None and c.domain is Domain.INT:
-                    v = np.asarray(c.data, dtype=np.int64)
-                    if c.mask is not None:
-                        v = v[np.asarray(c.mask)]
-                    info = (int(v.min()), int(v.max())) if v.size else "empty"
+                    with phase("groupby:keys"):
+                        v = to_host(c.data, np.int64)
+                        if c.mask is not None:
+                            v = v[to_host(c.mask)]
+                        info = ((int(v.min()), int(v.max())) if v.size
+                                else "empty")
             # staged output back into the store: under a budget it can spill
             # before the partial pass returns for it
             hout = block if f is src else as_handle(
@@ -1226,12 +1261,14 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
     # block sequence as its materialized input and makes the same regroup
     # decision, so both paths compute partials over the same row groupings.
     # (Key spans are global min/max — regrouping cannot change them.)
-    rp = preferred_row_parts(len(staged), grid or GRID_PREFS["fused_groupby"],
-                             total_bytes=sum(h.nbytes for h in staged))
-    if rp != len(staged):
-        staged = [row[0] for row in
-                  PartitionedFrame([[b] for b in staged])
-                  .repartition(row_parts=rp).handles]
+    with phase("groupby:regrid"):
+        rp = preferred_row_parts(len(staged),
+                                 grid or GRID_PREFS["fused_groupby"],
+                                 total_bytes=sum(h.nbytes for h in staged))
+        if rp != len(staged):
+            staged = [row[0] for row in
+                      PartitionedFrame([[b] for b in staged])
+                      .repartition(row_parts=rp).handles]
 
     spans = [i for i in infos if isinstance(i, tuple)]
     if single_key and spans and all(i is not None for i in infos):
@@ -1242,18 +1279,17 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
 
             def partial_block(block) -> dict:
                 with pinned(block) as f:
-                    c = f.col(keys[0])
-                    codes = np.asarray(c.data, dtype=np.int64) - gmin
-                    if c.mask is not None:
-                        codes = np.where(np.asarray(c.mask), codes, -1)
-                    return _block_partial(f, codes.astype(np.int32), G, need,
-                                          presence=True)
+                    with phase("groupby:keys"):
+                        codes = _dense_codes(f.col(keys[0]), gmin)
+                    return _block_partial(f, codes, G, need, presence=True)
 
             partials = dispatch_blocks(partial_block, staged)
-            combined = _combine_partials(partials, need + [_PRESENCE])
-            return _finalize_groupby(combined, staged[0], keys, aggs, G,
-                                     key_values=[gmin + i for i in range(G)],
-                                     drop_empty=True)
+            with phase("groupby:combine"):
+                combined = _combine_partials(partials, need + [_PRESENCE])
+            with phase("groupby:finalize"):
+                return _finalize_groupby(
+                    combined, staged[0], keys, aggs, G,
+                    key_values=[gmin + i for i in range(G)], drop_empty=True)
 
     # general path over the staged blocks: factorization needs a global view,
     # but the whole producer sweep still ran as one fused pool round
@@ -1610,11 +1646,11 @@ def _transpose(pf: PartitionedFrame) -> PartitionedFrame:
         out_mask = None
         if any(m is not None for m in masks):
             mm = jnp.stack([c.valid_mask() for c in f.columns], axis=1)
-            out_mask = np.asarray(kops.transpose(mm))
+            out_mask = to_host(kops.transpose(mm))
         # Wide-output fast path ("billions of columns", paper §4.2): one
         # device→host materialization, then zero-copy numpy views per column —
         # NOT n_cols separate device slices (O(µs) dispatch each).
-        out_np = np.asarray(out)
+        out_np = to_host(out)
         # second-transpose schema recovery (paper §3.3): the child's recorded
         # row-type vector (length == child.nrows == our ncols) gives the
         # output schema without re-running S(·) over values.
@@ -1668,9 +1704,9 @@ def _apply_udf_block(frame: Frame, udf: alg.Udf) -> Frame:
             cols.append(v)
         elif isinstance(v, tuple):
             data, mask = v
-            cols.append(Column(jnp.asarray(data), _infer_dom(data), mask, None))
+            cols.append(Column(to_device(data), _infer_dom(data), mask, None))
         else:
-            arr = jnp.asarray(v)
+            arr = to_device(v)
             cols.append(Column(arr, _infer_dom(arr), None, None))
     return Frame(cols, f.row_labels, labels_from_values(names))
 
@@ -1683,7 +1719,7 @@ def _map(pf: PartitionedFrame, udf: alg.Udf) -> PartitionedFrame:
 
 
 def _infer_dom(arr) -> Domain:
-    d = jnp.asarray(arr).dtype
+    d = to_device(arr).dtype
     if d == jnp.bool_:
         return Domain.BOOL
     if jnp.issubdtype(d, jnp.integer):
@@ -1789,7 +1825,7 @@ def _column_filter(pf: PartitionedFrame, predicate: alg.Expr) -> PartitionedFram
     keys = _key_rows_matrix(pf, refs)                     # (K, n)
     n = keys.shape[1]
     temp = Frame(
-        [Column(jnp.asarray(keys[i].astype(np.float32)), Domain.FLOAT) for i in range(len(refs))],
+        [Column(to_device(keys[i].astype(np.float32)), Domain.FLOAT) for i in range(len(refs))],
         RangeLabels(n),
         labels_from_values(list(refs)),
     )
@@ -1864,8 +1900,9 @@ def _fused_selection_mask(preds: Sequence[alg.Expr], frame: Frame) -> np.ndarray
         # predicate column on an accelerator backend.
         return _predicate_mask(frame, combined)
     fn = _compiled_predicate(combined, refs)
-    keep = fn([c.data for c in cols], [c.valid_mask() for c in cols])
-    return np.asarray(keep)
+    datas, masks = [c.data for c in cols], [c.valid_mask() for c in cols]
+    note_h2d(*datas, *masks)
+    return to_host(fn(datas, masks))
 
 
 # Compiled map-run programs: a run of consecutive elementwise MAP stages
@@ -1939,10 +1976,10 @@ def _frames_bit_equal(a: Frame, b: Frame) -> bool:
     for ca, cb in zip(a.columns, b.columns):
         if ca.domain is not cb.domain:
             return False
-        va, vb = np.asarray(ca.valid_mask()), np.asarray(cb.valid_mask())
+        va, vb = to_host(ca.valid_mask()), to_host(cb.valid_mask())
         if not np.array_equal(va, vb):
             return False
-        da, db = np.asarray(ca.data), np.asarray(cb.data)
+        da, db = to_host(ca.data), to_host(cb.data)
         if da.dtype != db.dtype:
             return False
         if not np.array_equal(np.where(va, da, 0), np.where(vb, db, 0)):
@@ -1977,6 +2014,7 @@ def _run_map_stages(frame: Frame, udfs: Sequence[alg.Udf]) -> Frame:
 
     datas = [c.data for c in f.columns]
     masks = [c.mask for c in f.columns]
+    note_h2d(*datas, *masks)     # the compiled chain's host operands
 
     if entry is not _MAP_JIT_MISS:
         fn, meta = entry
@@ -2024,18 +2062,21 @@ def _run_stages_block(frame: Frame, stages: Sequence[alg.Stage]) -> Frame:
                    and isinstance(stages[i].params["predicate"], alg.Expr)):
                 preds.append(stages[i].params["predicate"])
                 i += 1
-            if preds:
-                cur = cur.filter_rows(_fused_selection_mask(preds, cur))
-            else:  # opaque Udf predicate
-                cur = cur.filter_rows(_predicate_mask(cur, st.params["predicate"]))
-                i += 1
+            with phase("stage:select"):
+                if preds:
+                    cur = cur.filter_rows(_fused_selection_mask(preds, cur))
+                else:  # opaque Udf predicate
+                    cur = cur.filter_rows(
+                        _predicate_mask(cur, st.params["predicate"]))
+                    i += 1
         elif st.op == "map":
             # coalesce a run of elementwise maps → one jit-traced program
             udfs = []
             while i < len(stages) and stages[i].op == "map":
                 udfs.append(stages[i].params["udf"])
                 i += 1
-            cur = _run_map_stages(cur, udfs)
+            with phase("stage:map"):
+                cur = _run_map_stages(cur, udfs)
         elif st.op == "projection":
             cur = _project_block(cur, st.params["cols"])
             i += 1

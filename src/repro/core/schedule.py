@@ -172,7 +172,8 @@ from .faults import StatementCancelled, TaskError, env_int, is_retryable
 __all__ = [
     "get_pool", "pool_width", "reset_pool", "dispatch_blocks",
     "coalesce_factor", "preferred_row_parts", "output_row_parts",
-    "budget_max_block_bytes", "stats_scope", "node_scope", "GRID_PREFS",
+    "budget_max_block_bytes", "stats_scope", "node_scope", "count",
+    "GRID_PREFS",
     "task_retries", "retry_backoff_ms", "task_timeout_ms", "max_inflight",
     "configure_retries",
 ]
@@ -403,6 +404,14 @@ def _bump(st, name: str, d: int = 1) -> None:
             setattr(st, name, getattr(st, name) + d)
 
 
+def count(name: str, d: int = 1) -> None:
+    """Bump counter ``name`` of the stats scope installed on this thread —
+    the plan node being evaluated, carried onto pool threads by
+    :func:`dispatch_blocks` — and nothing outside one (transfer and compile
+    counters, bumped from wherever the work happens)."""
+    _bump(_STATS.get(), name, d)
+
+
 def _check_cancel(cancel, label: str) -> None:
     """Cooperative cancellation check between block tasks (the cancel token
     travels with the dispatch via ``config.propagate``)."""
@@ -505,7 +514,8 @@ def dispatch_blocks(fn: Callable, blocks: Sequence, stats=None, *,
     n = len(items)
     if n == 0:
         return []
-    st = stats if stats is not None else (_STATS.get() if attribute else None)
+    scope = _STATS.get()
+    st = stats if stats is not None else (scope if attribute else None)
 
     # resident blocks first (stable within each class, so the permutation is
     # deterministic given the residency snapshot); identity when nothing is
@@ -583,12 +593,19 @@ def dispatch_blocks(fn: Callable, blocks: Sequence, stats=None, *,
 
     def run_chunk(chunk_and_idxs) -> list:
         chunk, cidx = chunk_and_idxs
-        with _config.propagate(cfg, cancel, dsp):
-            if tr is None:
-                return chunk_body(chunk, cidx)
-            with tr.span(f"chunk:{label}", "task",
-                         args={"blocks": len(cidx), "first_block": cidx[0]}):
-                return chunk_body(chunk, cidx)
+        # the node's stats scope travels too: counters bumped inside the
+        # task (transfers, compiles) land in the statement's ExecStats
+        tok = _STATS.set(scope)
+        try:
+            with _config.propagate(cfg, cancel, dsp):
+                if tr is None:
+                    return chunk_body(chunk, cidx)
+                with tr.span(f"chunk:{label}", "task",
+                             args={"blocks": len(cidx),
+                                   "first_block": cidx[0]}):
+                    return chunk_body(chunk, cidx)
+        finally:
+            _STATS.reset(tok)
 
     try:
         out = _collect_dispatch(run_chunk, chunks, items, idxs, fn, retries,

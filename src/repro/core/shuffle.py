@@ -53,7 +53,6 @@ spillable unit); ``REPRO_SHUFFLE_SKEW_FACTOR`` sets the oversize threshold.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Any, Callable, Sequence
 
@@ -61,7 +60,6 @@ import numpy as np
 
 from . import algebra as alg
 from . import config as _config
-from . import trace as _trace
 from .dtypes import Domain
 from .faults import env_int
 from .frame import Column, Frame
@@ -70,6 +68,7 @@ from .partition import PartitionedFrame
 from .schedule import (GRID_PREFS, budget_max_block_bytes, coalesce_factor,
                        dispatch_blocks, node_scope, pool_width)
 from .store import as_handle, pinned, resolve
+from .trace import phase
 from . import physical as P
 
 __all__ = ["enabled", "configure", "bucket_count", "skew_factor",
@@ -227,17 +226,6 @@ def _bucket_frame(bid: int, key_handles: Sequence, select: Callable) -> Frame:
     return Frame(out.columns, RangeLabels(out.nrows), out.col_labels)
 
 
-def _phase(name: str):
-    """Trace span for one shuffle phase (bucketize / exchange / local /
-    gather) — a null context when tracing is off.  The phase spans sit
-    between the node span and the dispatch spans, so a traced profile shows
-    which *phase* of a JOIN/SORT the wall-clock went to."""
-    tr = _trace.current()
-    if tr is None:
-        return contextlib.nullcontext()
-    return tr.span(name, "phase")
-
-
 def _exchange(key_handles: Sequence, nb: int, select: Callable) -> list:
     """The exchange proper: bucket ids are computed ONCE per block key frame
     (one split task per block, stable-sorted so each bucket's piece keeps
@@ -360,7 +348,7 @@ def _gather_chunks(builders: Sequence[Callable[[], Frame]],
     def one(build):
         return as_handle(build(), recompute=build)
 
-    with node_scope(label), _phase(label):
+    with node_scope(label), phase(label):
         out = dispatch_blocks(one, list(builders))
     return PartitionedFrame([[h] for h in out])
 
@@ -546,7 +534,7 @@ def shuffled_join(left: PartitionedFrame, right: PartitionedFrame,
         total_rows = left.nrows + right.nrows
         key_bytes = total_rows * (K + 1) * 8
         B = bucket_count(total_rows, key_bytes)
-        with node_scope(f"{label}:exchange"), _phase(f"{label}:exchange"):
+        with node_scope(f"{label}:exchange"), phase(f"{label}:exchange"):
             # wide-int flags must agree across every block of BOTH inputs
             flag_items = ([(h, left_on) for h in lh]
                           + [(h, right_on) for h in rh])
@@ -560,7 +548,7 @@ def shuffled_join(left: PartitionedFrame, right: PartitionedFrame,
             joint = np.zeros_like(all_flags[0])
             for fl in all_flags:
                 joint = joint | fl
-            with _phase(f"{label}:bucketize"):
+            with phase(f"{label}:bucketize"):
                 lkeys = _join_key_handles(lh, loffs, left_on, joint, B)
                 rkeys = _join_key_handles(rh, roffs, right_on, joint, B)
                 lbuckets = _join_bucket_handles(lkeys, B)
@@ -571,7 +559,7 @@ def shuffled_join(left: PartitionedFrame, right: PartitionedFrame,
                 (K + 1) * 8 * h.nrows for h in lbuckets + rbuckets)
         mean_rows = max(1, total_rows // max(1, B))
         tasks = _local_join_tasks(lbuckets, rbuckets, mean_rows, stats)
-        with node_scope(f"{label}:local"), _phase(f"{label}:local"):
+        with node_scope(f"{label}:local"), phase(f"{label}:local"):
             results = dispatch_blocks(lambda a: _local_join(a, K), tasks)
         lidx, ridx, lvalid, rvalid = _merge_join_results(results, how)
         drop_right = tuple(right_on) if on is not None else ()
@@ -582,7 +570,7 @@ def shuffled_join(left: PartitionedFrame, right: PartitionedFrame,
     row_labels = None
     if preds and lidx.shape[0]:
         refs = sorted(frozenset().union(*[p.refs() for p in preds]), key=repr)
-        with node_scope(f"{label}:gather"), _phase(f"{label}:gather"):
+        with node_scope(f"{label}:gather"), phase(f"{label}:gather"):
             keep = _gather_pred_keep(preds, refs, lh, loffs, rh, roffs,
                                      lidx, ridx, lvalid, rvalid, drop_right,
                                      row_bytes)
@@ -761,11 +749,11 @@ def shuffled_sort(pf: PartitionedFrame, by: Sequence[Any], ascending: bool,
                 return np.asarray(P._fused_selection_mask(preds, f.induce()),
                                   dtype=bool)
 
-        with node_scope(f"{label}:exchange"), _phase(f"{label}:exchange"):
+        with node_scope(f"{label}:exchange"), phase(f"{label}:exchange"):
             keeps = dispatch_blocks(mask_task, blocks)
 
-    with node_scope(f"{label}:exchange"), _phase(f"{label}:exchange"):
-        with _phase(f"{label}:bucketize"):
+    with node_scope(f"{label}:exchange"), phase(f"{label}:exchange"):
+        with phase(f"{label}:bucketize"):
             key_handles, samples = _sort_key_handles(blocks, offs, by,
                                                      ascending, keeps)
             cuts = _splitters(samples, B)
@@ -798,7 +786,7 @@ def shuffled_sort(pf: PartitionedFrame, by: Sequence[Any], ascending: bool,
     oversized = [bh for bh in buckets if bh.nrows > thresh]
     refined: dict[int, list[np.ndarray]] = {}
     if oversized:
-        with node_scope(f"{label}:local"), _phase(f"{label}:local"):
+        with node_scope(f"{label}:local"), phase(f"{label}:local"):
             parts_lists = dispatch_blocks(refine_task, oversized)
         refined = {id(bh): parts for bh, parts in zip(oversized, parts_lists)}
     for bh in buckets:
@@ -818,7 +806,7 @@ def shuffled_sort(pf: PartitionedFrame, by: Sequence[Any], ascending: bool,
             return pos
         return pos[_lex_perm(keys)]
 
-    with node_scope(f"{label}:local"), _phase(f"{label}:local"):
+    with node_scope(f"{label}:local"), phase(f"{label}:local"):
         sorted_pos = dispatch_blocks(local_sort, work)
     idx = (np.concatenate(sorted_pos) if sorted_pos
            else np.empty(0, dtype=np.int64))

@@ -12,7 +12,9 @@ dimension: **time**, recorded as a span tree per statement,
                   → per-chunk pool tasks    (worker threads; parent span
                                              carried via ``config.propagate``)
                     → store spill / fault, retry backoff, injected faults
-              → shuffle bucketize/exchange/local/gather phases
+              → phases inside a node (``phase``): shuffle bucketize/
+                exchange/local/gather, groupby resolve/regrid/keys/
+                combine/finalize, stage select/map; backdated compiles
     service   → admission queue-wait + slot-hold per tenant
 
 into a bounded per-session ring buffer, exported as Chrome trace-event JSON
@@ -24,10 +26,13 @@ Design constraints (the reason this file is small and boring):
 
 * **Disabled is a no-op.**  Every instrumentation site is guarded by
   ``current()`` returning ``None`` — one contextvar read plus an attribute
-  check, no span allocation, no lock.  The ≤1% gate lives in
-  ``benchmarks/bench_trace.py`` (``BENCH_trace.json``) and the conftest
-  autouse guard asserts zero spans recorded in every non-``@pytest.mark.trace``
-  test, so tracing can never leak into the default path silently.
+  check, no span allocation, no lock.  The ≤1% gate of
+  ``benchmarks/bench_trace.py`` (``BENCH_trace.json``) is a CPU figure; on
+  one TPU v5e a fully traced ``tpch_q1_q6`` run (spans and the profiler)
+  read 2.9% fewer statements per second than an untraced one, inside the
+  cell's spread (``PERF.md``).  The conftest autouse guard asserts zero
+  spans recorded in every non-``@pytest.mark.trace`` test, so tracing can
+  never leak into the default path silently.
 * **ExecStats stays the counter source of truth.**  Spans carry counter
   *deltas* computed by the executor's existing snapshot-delta mechanism
   (``Executor._attribute_store_delta``), so the span-attached deltas of one
@@ -54,6 +59,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import itertools
 import json
 import os
@@ -65,7 +71,7 @@ from . import config as _config
 from .faults import env_int
 
 __all__ = [
-    "Span", "Tracer", "Metrics", "current", "configure", "reset",
+    "Span", "Tracer", "Metrics", "current", "configure", "reset", "phase",
     "recorded_total", "ring_size", "stats_metrics", "export",
     "chrome_trace_events", "validate_chrome_trace",
 ]
@@ -460,6 +466,19 @@ def reset() -> None:
     with _PROC_LOCK:
         _PROC = None
         _PROC_KEY = None
+
+
+_NULL_SCOPE = contextlib.nullcontext()
+
+
+def phase(name: str):
+    """Span for one step of the host work inside a plan node (a shuffle
+    phase, a groupby step, a fused stage), between the node's ``eval:`` span
+    and its dispatch spans, so an idle gap of the device is put down to the
+    step that caused it.  Off: the shared null context, one contextvar read.
+    Names are ``<op>:<step>`` with no digit after the colon."""
+    tr = current()
+    return _NULL_SCOPE if tr is None else tr.span(name, "phase")
 
 
 def export(path: str) -> str | None:

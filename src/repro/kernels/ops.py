@@ -9,6 +9,10 @@ Dispatch policy:
 
 Every wrapper has an identically-shaped oracle in ``ref.py``; tests sweep
 shapes × dtypes asserting allclose between the two.
+
+The entry points the engine calls (``segment_reduce_multi``, ``window_scan``,
+``onehot_encode``, ``transpose``) count their host numpy operands in
+``ExecStats.h2d_bytes`` (``core.transfer.note_h2d``).
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..core.transfer import note_h2d
 from . import ref
 from .block_transpose import block_transpose as _pallas_transpose
 from .decode_attention import decode_attention as _pallas_decode
@@ -43,6 +48,7 @@ def use_pallas() -> bool:
 
 # -----------------------------------------------------------------------------
 def transpose(x: jnp.ndarray) -> jnp.ndarray:
+    note_h2d(x)
     if use_pallas():
         w, orig = widen_for_kernel(x)
         return narrow_from_kernel(_pallas_transpose(w), orig)
@@ -109,6 +115,7 @@ def segment_reduce_multi(vals, valids, codes, *, bases, num_segments: int,
 
     ``pallas`` enters the jit cache key so a kernel-dispatch env flip between
     calls can't serve a program traced for the other mode."""
+    note_h2d(*vals, *valids, codes)
     return _segment_reduce_multi_prog(
         list(vals), list(valids), jnp.asarray(codes, jnp.int32),
         bases=tuple(bases), num_segments=num_segments, presence=presence,
@@ -116,6 +123,7 @@ def segment_reduce_multi(vals, valids, codes, *, bases, num_segments: int,
 
 
 def window_scan(x, op: str = "cumsum"):
+    note_h2d(x)
     if use_pallas():
         return _pallas_winscan(x, op)
     return ref.window_scan(x.astype(jnp.float32), op)
@@ -128,6 +136,7 @@ def linear_scan(a, b):
 
 
 def onehot_encode(codes, num_classes: int):
+    note_h2d(codes)
     if use_pallas():
         return _pallas_onehot(codes, num_classes)
     return ref.onehot_encode(codes, num_classes)

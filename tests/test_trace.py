@@ -10,27 +10,35 @@ the global ``ExecStats`` movement — even under a seeded chaos plan with a
 """
 import dataclasses
 import json
+import sys
 import threading
 import time
 
 import numpy as np
 import pytest
 
+import jax.numpy as jnp
+
+from repro import compile_cache
 from repro.core import EvalMode, Session
 from repro.core import algebra as alg
-from repro.core import faults, schedule, trace
+from repro.core import api, faults, schedule, trace
 from repro.core.algebra import GroupBy, Map, Selection, Udf, col, lit
 from repro.core.dtypes import Domain
-from repro.core.executor import ExecStats
+from repro.core.executor import ExecStats, StatsTee
 from repro.core.faults import StatementCancelled
 from repro.core.frame import Column, Frame
 from repro.core.labels import RangeLabels, labels_from_values
 from repro.core.service import QueryService
+from repro.core.transfer import note_h2d, to_host
+from repro.kernels import ops as kops
 
 pytestmark = pytest.mark.trace
 
 _DELTA_KEYS = ("spills", "faults", "spilled_bytes", "checksum_failures",
-               "recomputed_blocks", "budget_overruns", "faults_injected")
+               "recomputed_blocks", "budget_overruns", "faults_injected",
+               "d2h_bytes", "d2h_copies", "h2d_bytes", "compiles",
+               "compile_ns")
 
 
 @pytest.fixture(autouse=True)
@@ -253,6 +261,9 @@ def test_counter_deltas_sum_exactly_under_chaos(tmp_path):
         totals = tr.counter_totals(tr.last_stmt)
         for k in _DELTA_KEYS:
             assert totals.get(k, 0) == getattr(st1, k) - getattr(st0, k), k
+        # the transfer counters moved, so the sums above are not 0 == 0
+        assert st1.d2h_copies > st0.d2h_copies
+        assert st1.h2d_bytes > st0.h2d_bytes
     finally:
         s.close()
     leftovers = [p for p in tmp_path.rglob("*") if p.is_file()]
@@ -365,3 +376,172 @@ def test_service_traced_statement_records_admission_spans():
         assert "queue_wait" in names and "slot_hold" in names
         prof = tr.profile(h.stmt_id)
         assert prof["service"]["slot_hold_ns"] > 0
+
+
+# =============================================================================
+# phase spans inside the operators; transfer and compile counters
+# =============================================================================
+def _q1_like(s, n=3000, seed=12):
+    """A Q1-shaped statement: filter, two computed columns, a two-key
+    groupby over category keys (the general factorization path)."""
+    rng = np.random.default_rng(seed)
+    df = api.from_pydict({
+        "flag": rng.choice(["A", "N", "R"], n).tolist(),
+        "status": rng.choice(["F", "O"], n).tolist(),
+        "price": (rng.random(n) * 100 + 0.5).tolist(),
+        "disc": (rng.integers(0, 11, n) / 100 + 0.001).tolist(),
+        "ship": rng.integers(0, 200, n).tolist()}, session=s)
+    f = df[df["ship"] <= 150]
+    f["net"] = f["price"] * (f["disc"] * -1.0 + 1.0)
+    return f.groupby(["flag", "status"]).agg(
+        {"price": ["sum", "mean"], "net": ["sum"]})
+
+
+def test_phase_is_shared_null_context_when_off():
+    assert trace.current() is None
+    assert trace.phase("groupby:keys") is trace.phase("stage:map")
+
+
+def test_fused_groupby_records_phase_spans_under_its_node():
+    s = Session(mode=EvalMode.LAZY, trace=True, default_row_parts=3)
+    try:
+        _q1_like(s).collect()
+        tr = s.tracer
+        spans = [sp for sp in tr.snapshot() if sp.stmt == tr.last_stmt]
+    finally:
+        s.close()
+    by_id = {sp.id: sp for sp in spans}
+    node = [sp for sp in spans if sp.name == "eval:fused_groupby"]
+    assert len(node) == 1
+    steps = {sp.name: sp for sp in spans if sp.cat == "phase"}
+    for name in ("groupby:resolve", "groupby:keys", "groupby:combine",
+                 "groupby:finalize"):
+        assert steps[name].parent == node[0].id, name   # on the caller thread
+    # the per-column key uniques run on pool threads, under groupby:keys
+    uniq = [sp for sp in spans if sp.name == "keys:unique"]
+    assert len(uniq) == 2                                # one per key column
+    for sp in uniq:
+        chunk = by_id[sp.parent]
+        assert by_id[by_id[chunk.parent].parent] is steps["groupby:keys"]
+    stages = [sp for sp in spans if sp.name in ("stage:select", "stage:map")]
+    assert {sp.name for sp in stages} == {"stage:select", "stage:map"}
+    for sp in stages:                                    # inside pool chunks
+        assert by_id[sp.parent].name == "chunk:fused_groupby"
+    for sp in spans:     # one family per step in the idle-gap breakdown
+        assert not sp.name.partition(":")[2][:1].isdigit(), sp.name
+
+
+def test_host_take_counts_a_device_block_once():
+    col = Column(jnp.arange(1000, dtype=jnp.int32), Domain.INT)
+    st = ExecStats()
+    with schedule.stats_scope(st):
+        col.take(np.arange(10))
+        assert (st.d2h_bytes, st.d2h_copies) == (4000, 1)
+        col.take(np.arange(5, 50))           # the host copy is cached
+        col.filter(np.ones(1000, dtype=bool))
+    assert (st.d2h_bytes, st.d2h_copies) == (4000, 1)
+    # outside a stats scope nothing is attributed anywhere
+    Column(jnp.arange(8, dtype=jnp.int32), Domain.INT).take(np.arange(2))
+    assert (st.d2h_bytes, st.d2h_copies) == (4000, 1)
+
+
+def test_transfer_counted_on_a_pool_thread(clean_trace):
+    clean_trace.setenv("REPRO_POOL_WORKERS", "2")
+    schedule.reset_pool()
+    cols = [Column(jnp.full(100 + i, i, jnp.float32), Domain.FLOAT)
+            for i in range(6)]
+    st = ExecStats()
+    with schedule.stats_scope(st):
+        schedule.dispatch_blocks(lambda c: c.take(np.arange(3)), cols)
+    assert st.d2h_copies == 6
+    assert st.d2h_bytes == sum(4 * (100 + i) for i in range(6))
+
+
+def test_transfer_counters_exact_under_contention(clean_trace):
+    """More pool workers than cores bump one teed scope (global, tenant,
+    node tally) with a short switch interval: a lost update shows."""
+    clean_trace.setenv("REPRO_POOL_WORKERS", "8")
+    schedule.reset_pool()
+    arrays = [jnp.full(64 + i % 7, i, jnp.int32) for i in range(400)]
+    host = np.ones(10, np.int8)
+    targets = (ExecStats(), ExecStats(), ExecStats())
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with schedule.stats_scope(StatsTee(*targets)):
+            schedule.dispatch_blocks(
+                lambda a: (to_host(a), note_h2d(host)), arrays)
+    finally:
+        sys.setswitchinterval(old)
+    for st in targets:
+        assert st.d2h_bytes == sum(a.nbytes for a in arrays)
+        assert st.d2h_copies == len(arrays)
+        assert st.h2d_bytes == host.nbytes * len(arrays)
+
+
+def test_host_column_into_segment_reduce_multi_counts_h2d():
+    vals = np.arange(256, dtype=np.float32)
+    codes = (np.arange(256) % 4).astype(np.int32)
+    st = ExecStats()
+    with schedule.stats_scope(st):
+        out = kops.segment_reduce_multi([vals, vals], [None, None], codes,
+                                        bases=["sum", "count"],
+                                        num_segments=4)
+        # each host array once, though the value column is passed twice
+        assert st.h2d_bytes == vals.nbytes + codes.nbytes
+        kops.segment_reduce_multi([jnp.asarray(vals)], [None],
+                                  jnp.asarray(codes), bases=["sum"],
+                                  num_segments=4)
+    assert st.h2d_bytes == vals.nbytes + codes.nbytes   # device operands: 0
+    assert np.asarray(out[1]).tolist() == [64.0] * 4
+
+
+def test_new_shape_bumps_compiles_and_records_compile_span():
+    programs0, _ = compile_cache.counts()
+    s = Session(mode=EvalMode.LAZY, trace=True)
+    try:
+        # a block length no other test uses: its programs are built here
+        src = s.register_frame(_frame(6917, seed=13), row_parts=1)
+        st0 = dataclasses.replace(s.stats)
+        s.collect(_plan(src, name="trace_compile"))
+        st1, tr = s.stats, s.tracer
+        spans = [sp for sp in tr.snapshot() if sp.stmt == tr.last_stmt]
+    finally:
+        s.close()
+    assert st1.compiles > st0.compiles and st1.compile_ns > st0.compile_ns
+    assert compile_cache.counts()[0] - programs0 >= st1.compiles - st0.compiles
+    comp = [sp for sp in spans if sp.name == "compile"]
+    assert len(comp) == st1.compiles - st0.compiles
+    assert sum(sp.dur for sp in comp) == pytest.approx(
+        st1.compile_ns - st0.compile_ns, abs=100_000 * len(comp))
+    stmt = next(sp for sp in spans if sp.cat == "statement")
+    for sp in comp:            # backdated on the statement's own clock
+        assert sp.parent is not None
+        assert stmt.t0 <= sp.t0 and sp.t0 + sp.dur <= stmt.t0 + stmt.dur
+
+
+def test_tracing_off_records_nothing_but_counters_count():
+    before = trace.recorded_total()
+    s = Session(mode=EvalMode.LAZY)
+    try:
+        src = s.register_frame(_frame(400, seed=14), row_parts=4)
+        st0 = dataclasses.replace(s.stats)
+        assert s.collect(_plan(src, name="trace_off_counts")).nrows > 0
+        st1 = s.stats
+    finally:
+        s.close()
+    assert trace.recorded_total() == before
+    assert st1.d2h_copies > st0.d2h_copies and st1.d2h_bytes > st0.d2h_bytes
+    assert st1.h2d_bytes > st0.h2d_bytes
+
+
+def test_transfer_counters_reach_the_tenant_stats():
+    with QueryService(background_workers=2) as svc:
+        busy = svc.session(mode=EvalMode.LAZY)
+        idle = svc.session(mode=EvalMode.LAZY)
+        src = busy.register_frame(_frame(400, seed=15), row_parts=4)
+        busy.submit(_plan(src, name="trace_tenant_xfer")).result(timeout=30.0)
+        for k in ("d2h_bytes", "d2h_copies", "h2d_bytes"):
+            assert getattr(busy.stats, k) > 0, k
+            assert getattr(idle.stats, k) == 0, k
+            assert getattr(busy.stats, k) == getattr(svc.stats, k), k
