@@ -189,10 +189,10 @@ class ExecStats:
                                     pool pressure: ``QueryService.
                                     tenant_report`` ranks sessions by these).
 
-    Transfer and compile counters — always on (int adds), bumped through
-    the node's stats scope (``schedule.count``), on the caller thread or a
-    pool thread, so they land here, in the tenant's stats and in the node's
-    span (``SCOPE_COUNTERS``):
+    Transfer, compile and groupby-route counters — always on (int adds),
+    bumped through the node's stats scope (``schedule.count``), on the
+    caller thread or a pool thread, so they land here, in the tenant's stats
+    and in the node's span (``SCOPE_COUNTERS``):
 
       * ``d2h_bytes``             — bytes copied device→host
                                     (``transfer.to_host``: row takes,
@@ -210,7 +210,16 @@ class ExecStats:
       * ``compiles``              — XLA programs built while a node ran,
                                     compiled or loaded from the persistent
                                     cache (``compile_cache.listen``);
-      * ``compile_ns``            — the time those builds took.
+      * ``compile_ns``            — the time those builds took;
+      * ``groupby_dense``         — GROUPBY nodes (plain or fused) whose
+                                    group codes came from the keys' dense
+                                    rank ranges (``physical._dense_keys``:
+                                    dictionaries and small INT spans, mixed
+                                    radix, no sort);
+      * ``groupby_factorized``    — GROUPBY nodes with keys that took the
+                                    general factorization
+                                    (``physical._factorize_keys``).  A
+                                    groupby without keys counts neither.
     """
 
     evaluated_nodes: int = 0
@@ -251,6 +260,8 @@ class ExecStats:
     h2d_bytes: int = 0
     compiles: int = 0
     compile_ns: int = 0
+    groupby_dense: int = 0
+    groupby_factorized: int = 0
 
     @property
     def blocks_per_dispatch(self) -> float:
@@ -260,7 +271,7 @@ class ExecStats:
 # the ExecStats counters bumped through the node's stats scope: a traced
 # node's span carries its own delta of each, tallied as they are bumped
 SCOPE_COUNTERS = ("d2h_bytes", "d2h_copies", "h2d_bytes", "compiles",
-                  "compile_ns")
+                  "compile_ns", "groupby_dense", "groupby_factorized")
 
 _TEE_LOCK = threading.Lock()
 

@@ -69,9 +69,10 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 import os
 import threading
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +83,7 @@ from .dtypes import Domain, common_storage, parse_column, storage_dtype
 from .frame import Column, Frame
 from .labels import CodedLabels, IntLabels, Labels, RangeLabels, labels_from_values
 from .partition import PartitionedFrame
-from .schedule import (GRID_PREFS, dispatch_blocks, output_row_parts,
+from .schedule import (GRID_PREFS, count, dispatch_blocks, output_row_parts,
                        preferred_row_parts)
 from .store import as_handle, pinned, resolve
 from .trace import phase
@@ -933,6 +934,8 @@ def _mask_all(frame: Frame, valid: np.ndarray | None) -> Frame:
 
 # ---- GROUPBY ----------------------------------------------------------------
 _COMBINE = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
+# the most group slots a dense-code groupby allocates (G of its programs)
+_DENSE_CAP = 65536
 
 
 def _groupby(pf: PartitionedFrame, keys: Sequence[Any], aggs: Sequence[tuple]) -> PartitionedFrame:
@@ -955,37 +958,140 @@ def _groupby(pf: PartitionedFrame, keys: Sequence[Any], aggs: Sequence[tuple]) -
 
 def _groupby_blocks(row_blocks: list, keys: Sequence[Any],
                     aggs: Sequence[tuple]) -> PartitionedFrame:
-    # the general factorization needs a global view of every block's keys, so
-    # this path materializes all blocks (handles fault here); the fused
-    # dense-int path above it is the memory-governed one
+    # the key step needs a global view of every block's keys (dictionaries,
+    # INT spans, or the general factorization), so this path materializes
+    # all blocks (handles fault here); the fused single-INT path above it is
+    # the memory-governed one
     with phase("groupby:resolve"):
         row_blocks = [resolve(b) for b in row_blocks]
     with phase("groupby:keys"):
-        dense = _dense_int_key(row_blocks, keys) if len(keys) == 1 else None
+        dense = _dense_keys(row_blocks, keys)
         if dense is None:
             codes_per_block, G, rep_sorted = _factorize_keys(row_blocks, keys)
-        else:
-            # dense small-range INT key: no host factorization (paper's
-            # groupby(n) benchmark shape: "passenger_count"-like keys).
-            # codes = v - min per block; empty groups dropped after the
-            # combine.  Avoids the serial np.unique Amdahl term.
-            vmin, G = dense
-            codes_per_block = [_dense_codes(b.col(keys[0]), vmin)
-                               for b in row_blocks]
     if dense is not None:
-        return _groupby_with_codes(row_blocks, keys, aggs, codes_per_block,
-                                   int(G), key_values=[int(vmin) + i for i in range(int(G))],
-                                   drop_empty=True)
-    return _groupby_with_codes(row_blocks, keys, aggs, codes_per_block, G,
+        return _groupby_with_codes(row_blocks, keys, aggs, dense=dense)
+    if keys:
+        count("groupby_factorized")
+    return _groupby_with_codes(row_blocks, keys, aggs, G=G,
+                               codes_per_block=codes_per_block,
                                rep_sorted=rep_sorted)
 
 
-def _dense_codes(c: Column, vmin: int) -> np.ndarray:
-    """Group codes ``v - vmin`` of a dense INT key column, -1 where null."""
-    codes = to_host(c.data, np.int64) - vmin
-    if c.mask is not None:
-        codes = np.where(to_host(c.mask), codes, -1)
-    return codes.astype(np.int32)
+def _value_order(v) -> tuple:
+    """Sort key of one group key value: numbers by value, anything else by
+    type name then value.  Groups come out in the lexicographic order of
+    their key tuples under it, whichever route coded them."""
+    return ("num", v) if isinstance(v, (int, float, bool)) else (str(type(v)), v)
+
+
+class _DenseKey(NamedTuple):
+    """One groupby key mapped onto a dense rank range: ``values[r]`` is the
+    key value of rank r, in group order.  An INT key ranks ``v - vmin``; a
+    coded key reads a block's codes through ``luts[id(dictionary)]``, whose
+    last entry (-1) also takes the null code."""
+    values: Sequence
+    domain: Domain
+    vmin: int | None = None
+    luts: dict | None = None
+
+    def ranks(self, c: Column) -> np.ndarray:
+        """int32 ranks of one block's key column, NULL_CODE where null."""
+        if self.vmin is None:
+            codes = to_host(c.data)   # a 0-row coded column may be float
+            r = self.luts[id(c.dictionary)][codes.astype(np.int32, copy=False)]
+        else:
+            r = (to_host(c.data, np.int64) - self.vmin).astype(np.int32)
+        if c.mask is not None:
+            r = np.where(to_host(c.mask), r, NULL_CODE)
+        return r
+
+
+def _dense_keys(row_blocks: list[Frame], keys) -> list[_DenseKey] | None:
+    """Each key's dense rank range, or None where the general factorization
+    must run: a key that is neither INT nor coded, an INT key with no valid
+    value, an INT range or a dictionary union over ``_DENSE_CAP``, or a
+    product of the ranges over it."""
+    if not keys or not row_blocks:
+        return None
+    out, space = [], 1
+    for k in keys:
+        try:
+            cols = [b.col(k) for b in row_blocks]
+        except KeyError:
+            return None
+        dom = cols[0].domain
+        if any(c.domain is not dom for c in cols):
+            return None
+        if dom is Domain.INT:
+            span = _int_span(cols)
+            dk = (None if span is None else
+                  _DenseKey(range(span[0], span[1] + 1), dom, vmin=span[0]))
+        elif dom.is_coded:
+            dk = _coded_key(cols, dom)
+        else:
+            return None
+        if dk is None:
+            return None
+        space *= len(dk.values)
+        if space > _DENSE_CAP:
+            return None
+        out.append(dk)
+    return out
+
+
+def _int_span(cols: list[Column]) -> tuple[int, int] | None:
+    """(min, max) of the valid values of an INT key's blocks; None if none."""
+    vmin, vmax = None, None
+    for c in cols:
+        v = to_host(c.data, np.int64)
+        if c.mask is not None:
+            v = v[to_host(c.mask)]
+        if v.size == 0:
+            continue
+        lo, hi = int(v.min()), int(v.max())
+        vmin = lo if vmin is None else min(vmin, lo)
+        vmax = hi if vmax is None else max(vmax, hi)
+    return None if vmin is None else (vmin, vmax)
+
+
+def _coded_key(cols: list[Column], dom: Domain) -> _DenseKey | None:
+    """Rank range of a coded key: the union of its blocks' dictionaries in
+    value order, and one local-code → rank LUT per distinct dictionary.
+    Values are equal where their strings are, as ``_row_keys`` hashes them;
+    None where two differing values share a string or the union is over
+    ``_DENSE_CAP``."""
+    tables = {id(c.dictionary): c.dictionary or () for c in cols}
+    union: dict[str, Any] = {}
+    for table in tables.values():
+        for v in table:
+            prev = union.setdefault(str(v), v)
+            if type(prev) is not type(v) or prev != v:
+                return None
+        if len(union) > _DENSE_CAP:
+            return None
+    if not union:
+        return None
+    values = sorted(union.values(), key=_value_order)
+    rank = {str(v): r for r, v in enumerate(values)}
+    luts = {i: np.asarray([rank[str(v)] for v in t] + [NULL_CODE], np.int32)
+            for i, t in tables.items()}
+    return _DenseKey(values, dom, luts=luts)
+
+
+def _dense_codes(block: Frame, keys, dense: Sequence[_DenseKey]) -> np.ndarray:
+    """A block's group codes: the mixed-radix slot ``(r0·c1 + r1)·c2 + …``
+    of its keys' ranks, which is the key tuple's lexicographic place, and
+    NULL_CODE where any key is null (pandas drops null keys)."""
+    ranks = [dk.ranks(block.col(k)) for k, dk in zip(keys, dense)]
+    code = ranks[0]
+    for r, dk in zip(ranks[1:], dense[1:]):
+        code = code * np.int32(len(dk.values)) + r
+    if len(ranks) > 1:
+        null = ranks[0] < 0
+        for r in ranks[1:]:
+            null |= r < 0
+        code[null] = NULL_CODE
+    return code
 
 
 def _factorize_keys(row_blocks: list, keys: Sequence[Any]):
@@ -1013,8 +1119,7 @@ def _factorize_keys(row_blocks: list, keys: Sequence[Any]):
             return tuple(row_blocks[bi].col(k).value_at(local) for k in keys)
         rep_vals = [decode_row(int(gi)) for gi in first_global]
         perm = sorted(range(len(rep_vals)), key=lambda i: tuple(
-            (str(type(v)), v) if not isinstance(v, (int, float, bool)) else ("num", v)
-            for v in rep_vals[i]))
+            _value_order(v) for v in rep_vals[i]))
         order = uniq_ids[np.asarray(perm, dtype=np.int64)] if len(perm) else uniq_ids
         rep_sorted = [rep_vals[i] for i in perm]
         G = len(order)
@@ -1028,36 +1133,6 @@ def _factorize_keys(row_blocks: list, keys: Sequence[Any]):
         rep_sorted = None
         codes_per_block = [np.zeros(b.nrows, dtype=np.int32) for b in row_blocks]
     return codes_per_block, G, rep_sorted
-
-
-def _dense_int_key(row_blocks: list[Frame], keys) -> tuple[int, int] | None:
-    """(vmin, G) when the single key column is INT with a small value range —
-    codes are then ``v - vmin`` with no host factorization."""
-    try:
-        cols = [b.col(keys[0]) for b in row_blocks]
-    except KeyError:
-        return None
-    if any(c.domain is not Domain.INT for c in cols):
-        return None
-    vmin, vmax = None, None
-    for c in cols:
-        v = to_host(c.data, np.int64)
-        if c.mask is not None:
-            mask = to_host(c.mask)
-            if not mask.any():
-                continue
-            v = v[mask]
-        if v.size == 0:
-            continue
-        lo, hi = int(v.min()), int(v.max())
-        vmin = lo if vmin is None else min(vmin, lo)
-        vmax = hi if vmax is None else max(vmax, hi)
-    if vmin is None:
-        return None
-    g = vmax - vmin + 1
-    if g > 65536:
-        return None
-    return vmin, g
 
 
 def _agg_need(aggs) -> list[tuple[Any, str]]:
@@ -1108,35 +1183,52 @@ def _combine_partials(partials: Sequence[dict], want: Sequence[tuple]) -> dict:
     return combined
 
 
-def _groupby_with_codes(row_blocks: list[Frame], keys, aggs, codes_per_block,
-                        G: int, rep_sorted=None, key_values=None,
-                        drop_empty: bool = False) -> PartitionedFrame:
-    # ---- per-block partials (parallel; MXU segment_reduce) ------------------
+def _groupby_with_codes(blocks: list, keys, aggs, *, dense=None, G: int = 1,
+                        codes_per_block=None, rep_sorted=None) -> PartitionedFrame:
+    """Per-block partials (parallel; one ``segment_reduce_multi`` program
+    each), tree combine, finalize.  With ``dense`` keys each block's codes
+    are computed inside its own task, under ``groupby:keys``, over every
+    slot of the rank space, and the slots no row filled drop after the
+    combine; else the blocks' ``codes_per_block`` index ``G`` groups."""
     need = _agg_need(aggs)
+    if dense is not None:
+        count("groupby_dense")
+        G = math.prod(len(dk.values) for dk in dense)
 
-    def block_partial(args) -> dict:
-        block, codes = args
-        return _block_partial(block, codes, G, need, presence=drop_empty)
+        def block_partial(block) -> dict:
+            with pinned(block) as f:
+                with phase("groupby:keys"):
+                    codes = _dense_codes(f, keys, dense)
+                return _block_partial(f, codes, G, need, presence=True)
 
-    partials = dispatch_blocks(block_partial, list(zip(row_blocks, codes_per_block)))
-    want = need + [_PRESENCE] if drop_empty else need
+        partials = dispatch_blocks(block_partial, blocks)
+        need = need + [_PRESENCE]
+    else:
+        partials = dispatch_blocks(
+            lambda bc: _block_partial(bc[0], bc[1], G, need, presence=False),
+            list(zip(blocks, codes_per_block)))
     with phase("groupby:combine"):
-        combined = _combine_partials(partials, want)
+        combined = _combine_partials(partials, need)
     with phase("groupby:finalize"):
-        return _finalize_groupby(
-            combined, row_blocks[0] if row_blocks else None, keys, aggs, G,
-            rep_sorted, key_values, drop_empty)
+        return _finalize_groupby(combined, blocks[0] if blocks else None,
+                                 keys, aggs, G, rep_sorted, dense)
 
 
 def _finalize_groupby(combined: dict, template: Frame | None, keys, aggs,
-                      G: int, rep_sorted=None, key_values=None,
-                      drop_empty: bool = False) -> PartitionedFrame:
+                      G: int, rep_sorted=None, dense=None) -> PartitionedFrame:
     out_cols: list[Column] = []
     out_names: list[Any] = []
-    # key columns first (representative decoded values, sorted order)
-    if keys and key_values is not None:      # dense-int fast path
-        out_cols.append(_host_column(list(key_values), Domain.INT))
-        out_names.append(keys[0])
+    keep = None
+    # key columns first (the groups' key values, in group order)
+    if dense is not None:
+        # the slots some row filled are the groups; a slot's mixed-radix
+        # digits are its keys' ranks
+        keep = np.nonzero(to_host(combined[_PRESENCE]) > 0)[0]
+        digits = np.unravel_index(keep, [len(dk.values) for dk in dense])
+        for kname, dk, ranks in zip(keys, dense, digits):
+            out_cols.append(_host_column([dk.values[r] for r in ranks.tolist()],
+                                         dk.domain))
+            out_names.append(kname)
     elif keys:
         template = resolve(template)   # only this branch needs block data
         for kpos, kname in enumerate(keys):
@@ -1168,14 +1260,13 @@ def _finalize_groupby(combined: dict, template: Frame | None, keys, aggs,
         mask = cnt > 0 if cnt is not None else None
         dom = Domain.INT if func == "count" else (Domain.BOOL if func in ("any", "all") else Domain.FLOAT)
         data = vals.astype(storage_dtype(dom))
-        out_cols.append(Column(data, dom, mask if func != "count" else None, None))
+        col = Column(data, dom, mask if func != "count" else None, None)
+        out_cols.append(col if keep is None else col.take(keep))
         out_names.append(out_label)
 
-    frame = Frame(out_cols, RangeLabels(G), labels_from_values(out_names))
-    if drop_empty:
-        present = to_host(combined[("__presence__", "sum")]) > 0
-        frame = frame.filter_rows(present)
-    return _output_pf(frame)
+    nrows = G if keep is None else len(keep)
+    return _output_pf(Frame(out_cols, RangeLabels(nrows),
+                            labels_from_values(out_names)))
 
 
 def _bases_for(func: str) -> tuple[str, ...]:
@@ -1219,10 +1310,10 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
     plus all ``segment_reduce`` partials in a single compiled program
     (``kernels.ops.segment_reduce_multi``) — a global static G means one XLA
     executable shared by every block and every query on the same schema,
-    where per-block local ranges would recompile per distinct span.  Keys
-    that don't qualify (non-INT, multi-key, range > 65536) fall back to the
-    general factorization over the staged blocks — the producer sweep still
-    ran fused, in one pool round instead of one per operator."""
+    where per-block local ranges would recompile per distinct span.  Other
+    key sets (coded or multi-key, range > 65536) go to ``_groupby_blocks``
+    over the staged blocks — the producer sweep still ran fused, in one pool
+    round instead of one per operator."""
     pf1 = pf.repartition(col_parts=1)
     blocks = [row[0] for row in pf1.handles]
     single_key = len(keys) == 1
@@ -1274,25 +1365,13 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
     if single_key and spans and all(i is not None for i in infos):
         gmin = min(i[0] for i in spans)
         G = max(i[1] for i in spans) - gmin + 1
-        if G <= 65536:
-            need = _agg_need(aggs)
+        if G <= _DENSE_CAP:
+            dense = [_DenseKey(range(gmin, gmin + G), Domain.INT, vmin=gmin)]
+            return _groupby_with_codes(staged, keys, aggs, dense=dense)
 
-            def partial_block(block) -> dict:
-                with pinned(block) as f:
-                    with phase("groupby:keys"):
-                        codes = _dense_codes(f.col(keys[0]), gmin)
-                    return _block_partial(f, codes, G, need, presence=True)
-
-            partials = dispatch_blocks(partial_block, staged)
-            with phase("groupby:combine"):
-                combined = _combine_partials(partials, need + [_PRESENCE])
-            with phase("groupby:finalize"):
-                return _finalize_groupby(
-                    combined, staged[0], keys, aggs, G,
-                    key_values=[gmin + i for i in range(G)], drop_empty=True)
-
-    # general path over the staged blocks: factorization needs a global view,
-    # but the whole producer sweep still ran as one fused pool round
+    # every other key set over the staged blocks: dense codes where each key
+    # has a small rank range, else the general factorization; either needs
+    # a global view, but the whole producer sweep still ran as one pool round
     return _groupby_blocks(staged, keys, aggs)
 
 

@@ -109,8 +109,9 @@ def test_producer_into_groupby_under_pallas_kernels(use_pallas_kernels):
 
 
 def test_producer_into_groupby_string_key_general_path():
-    # coded (string) key cannot take the dense-int path: the general
-    # factorization must still run over the staged (fused) blocks
+    # a coded (string) key cannot take the fused single-INT path: the
+    # groupby's own key step (dense codes from the dictionary) must still
+    # run over the staged (fused) blocks
     f = _mk_frame()
     store = {"f0": PartitionedFrame.from_frame(f, row_parts=5)}
     src = alg.Source("f0", nrows=f.nrows, ncols=f.ncols)

@@ -381,19 +381,20 @@ def test_service_traced_statement_records_admission_spans():
 # =============================================================================
 # phase spans inside the operators; transfer and compile counters
 # =============================================================================
-def _q1_like(s, n=3000, seed=12):
+def _q1_like(s, keys=("flag", "status"), n=3000, seed=12):
     """A Q1-shaped statement: filter, two computed columns, a two-key
-    groupby over category keys (the general factorization path)."""
+    groupby — over category keys (dense codes) by default."""
     rng = np.random.default_rng(seed)
     df = api.from_pydict({
         "flag": rng.choice(["A", "N", "R"], n).tolist(),
         "status": rng.choice(["F", "O"], n).tolist(),
         "price": (rng.random(n) * 100 + 0.5).tolist(),
         "disc": (rng.integers(0, 11, n) / 100 + 0.001).tolist(),
+        "tax": (rng.integers(0, 9, n) / 100 + 0.001).tolist(),
         "ship": rng.integers(0, 200, n).tolist()}, session=s)
     f = df[df["ship"] <= 150]
     f["net"] = f["price"] * (f["disc"] * -1.0 + 1.0)
-    return f.groupby(["flag", "status"]).agg(
+    return f.groupby(list(keys)).agg(
         {"price": ["sum", "mean"], "net": ["sum"]})
 
 
@@ -402,10 +403,15 @@ def test_phase_is_shared_null_context_when_off():
     assert trace.phase("groupby:keys") is trace.phase("stage:map")
 
 
-def test_fused_groupby_records_phase_spans_under_its_node():
+# two category keys take dense codes (per-block code spans on pool threads,
+# no sort); two FLOAT keys take the general factorization, whose per-column
+# uniques run as pool tasks under the caller's key step
+@pytest.mark.parametrize("keys", [("flag", "status"), ("disc", "tax")],
+                         ids=["dense_codes", "factorized"])
+def test_fused_groupby_records_phase_spans_under_its_node(keys):
     s = Session(mode=EvalMode.LAZY, trace=True, default_row_parts=3)
     try:
-        _q1_like(s).collect()
+        _q1_like(s, keys).collect()
         tr = s.tracer
         spans = [sp for sp in tr.snapshot() if sp.stmt == tr.last_stmt]
     finally:
@@ -413,16 +419,31 @@ def test_fused_groupby_records_phase_spans_under_its_node():
     by_id = {sp.id: sp for sp in spans}
     node = [sp for sp in spans if sp.name == "eval:fused_groupby"]
     assert len(node) == 1
-    steps = {sp.name: sp for sp in spans if sp.cat == "phase"}
+    steps = {}
+    for sp in spans:               # the steps on the caller thread
+        if sp.cat == "phase" and sp.parent == node[0].id:
+            steps.setdefault(sp.name, []).append(sp)
     for name in ("groupby:resolve", "groupby:keys", "groupby:combine",
                  "groupby:finalize"):
-        assert steps[name].parent == node[0].id, name   # on the caller thread
-    # the per-column key uniques run on pool threads, under groupby:keys
+        assert len(steps.get(name, ())) == 1, name
     uniq = [sp for sp in spans if sp.name == "keys:unique"]
-    assert len(uniq) == 2                                # one per key column
-    for sp in uniq:
-        chunk = by_id[sp.parent]
-        assert by_id[by_id[chunk.parent].parent] is steps["groupby:keys"]
+    block_keys = [sp for sp in spans if sp.name == "groupby:keys"
+                  and sp.parent != node[0].id]
+    if keys == ("flag", "status"):
+        assert uniq == []                                # no sort at all
+        # each block's codes are computed in its partial task, in a chunk
+        assert block_keys
+        for sp in block_keys:
+            chunk = by_id[sp.parent]
+            assert chunk.name == "chunk:fused_groupby"
+            assert by_id[chunk.parent].parent == node[0].id
+    else:
+        assert block_keys == []
+        # the per-column key uniques run on pool threads, under groupby:keys
+        assert len(uniq) == 2                            # one per key column
+        for sp in uniq:
+            chunk = by_id[sp.parent]
+            assert by_id[by_id[chunk.parent].parent] is steps["groupby:keys"][0]
     stages = [sp for sp in spans if sp.name in ("stage:select", "stage:map")]
     assert {sp.name for sp in stages} == {"stage:select", "stage:map"}
     for sp in stages:                                    # inside pool chunks
