@@ -525,7 +525,9 @@ def _keys_to_ids(*key_mats: np.ndarray) -> list[np.ndarray]:
 # per-block key extraction runs through ``schedule.dispatch_blocks``, the
 # per-block key matrices are jointly factorized in one host pass, and the
 # first-occurrence / anti-join keep masks are applied blockwise — the input
-# keeps its partitioned form end to end (no ``to_frame()`` concat).
+# keeps its partitioned form end to end (no ``to_frame()`` concat).  The
+# three steps are the caller's phase spans ``dedup:keys`` (regrid and the
+# key-extraction round), ``dedup:ids`` and ``dedup:keep``.
 
 
 def _block_dedup_enabled() -> bool:
@@ -642,29 +644,33 @@ def _difference(left: PartitionedFrame, right: PartitionedFrame, stats=None,
     masks filter the left blocks in place."""
     if not _block_dedup_enabled():
         return _difference_serial(left, right, stats, pre_l, pre_r, post)
-    lblocks = _dedup_grid_blocks(left, grid, "difference")
-    rblocks = _dedup_grid_blocks(right, grid, "difference")
     preds, proj, rest = _split_consumer_stages(post)
-    items = ([(b, None, pre_l, preds) for b in lblocks]
-             + [(b, None, pre_r, ()) for b in rblocks])
-    results = dispatch_blocks(_key_block, items)
-    frames, mats, pred_keeps = _joint_key_mats(results, None)
-    nl = len(lblocks)
-    if stats is not None:
-        stats.dedup_blocks += len(frames)
-        stats.dedup_key_rows += sum(int(m.shape[0]) for m in mats)
-    ids = _keys_to_ids(*mats)
-    lids, rids = ids[:nl], ids[nl:]
-    rset = np.unique(np.concatenate(rids))
-    keeps = []
-    for lid, pk in zip(lids, pred_keeps[:nl]):
-        k = ~np.isin(lid, rset)
-        if pk is not None:
-            k = k & pk
-        keeps.append(k)
+    with phase("dedup:keys"):
+        lblocks = _dedup_grid_blocks(left, grid, "difference")
+        rblocks = _dedup_grid_blocks(right, grid, "difference")
+        items = ([(b, None, pre_l, preds) for b in lblocks]
+                 + [(b, None, pre_r, ()) for b in rblocks])
+        results = dispatch_blocks(_key_block, items)
+    with phase("dedup:ids"):
+        frames, mats, pred_keeps = _joint_key_mats(results, None)
+        nl = len(lblocks)
+        if stats is not None:
+            stats.dedup_blocks += len(frames)
+            stats.dedup_key_rows += sum(int(m.shape[0]) for m in mats)
+        ids = _keys_to_ids(*mats)
+        lids, rids = ids[:nl], ids[nl:]
+        rset = np.unique(np.concatenate(rids))
+        keeps = []
+        for lid, pk in zip(lids, pred_keeps[:nl]):
+            k = ~np.isin(lid, rset)
+            if pk is not None:
+                k = k & pk
+            keeps.append(k)
     if stats is not None:
         stats.gather_rows += int(sum(int(k.sum()) for k in keeps))
-    return _dedup_finish(_apply_keep_blocks(frames[:nl], keeps, proj), rest)
+    with phase("dedup:keep"):
+        kept = _apply_keep_blocks(frames[:nl], keeps, proj)
+    return _dedup_finish(kept, rest)
 
 
 def _drop_duplicates(pf: PartitionedFrame, subset, stats=None,
@@ -676,32 +682,36 @@ def _drop_duplicates(pf: PartitionedFrame, subset, stats=None,
     to compare, so every row survives — pandas semantics."""
     if not _block_dedup_enabled():
         return _drop_duplicates_serial(pf, subset, stats, pre, post)
-    blocks = _dedup_grid_blocks(pf, grid, "drop_duplicates")
     preds, proj, rest = _split_consumer_stages(post)
-    results = dispatch_blocks(_key_block,
-                              [(b, subset, pre, preds) for b in blocks])
-    frames, mats, pred_keeps = _joint_key_mats(results, subset)
-    total = sum(int(m.shape[0]) for m in mats)
-    if stats is not None:
-        stats.dedup_blocks += len(frames)
-        stats.dedup_key_rows += total
-    if mats[0].shape[1] == 0:
-        keep_global = np.ones(total, dtype=bool)
-    else:
-        all_ids = np.concatenate(_keys_to_ids(*mats))
-        _, first = np.unique(all_ids, return_index=True)
-        keep_global = np.zeros(total, dtype=bool)
-        keep_global[first] = True
-    keeps, off = [], 0
-    for m, pk in zip(mats, pred_keeps):
-        k = keep_global[off:off + m.shape[0]]
-        off += m.shape[0]
-        if pk is not None:
-            k = k & pk
-        keeps.append(k)
+    with phase("dedup:keys"):
+        blocks = _dedup_grid_blocks(pf, grid, "drop_duplicates")
+        results = dispatch_blocks(_key_block,
+                                  [(b, subset, pre, preds) for b in blocks])
+    with phase("dedup:ids"):
+        frames, mats, pred_keeps = _joint_key_mats(results, subset)
+        total = sum(int(m.shape[0]) for m in mats)
+        if stats is not None:
+            stats.dedup_blocks += len(frames)
+            stats.dedup_key_rows += total
+        if mats[0].shape[1] == 0:
+            keep_global = np.ones(total, dtype=bool)
+        else:
+            all_ids = np.concatenate(_keys_to_ids(*mats))
+            _, first = np.unique(all_ids, return_index=True)
+            keep_global = np.zeros(total, dtype=bool)
+            keep_global[first] = True
+        keeps, off = [], 0
+        for m, pk in zip(mats, pred_keeps):
+            k = keep_global[off:off + m.shape[0]]
+            off += m.shape[0]
+            if pk is not None:
+                k = k & pk
+            keeps.append(k)
     if stats is not None:
         stats.gather_rows += int(sum(int(k.sum()) for k in keeps))
-    return _dedup_finish(_apply_keep_blocks(frames, keeps, proj), rest)
+    with phase("dedup:keep"):
+        kept = _apply_keep_blocks(frames, keeps, proj)
+    return _dedup_finish(kept, rest)
 
 
 def _difference_serial(left: PartitionedFrame, right: PartitionedFrame,

@@ -566,3 +566,82 @@ def test_transfer_counters_reach_the_tenant_stats():
             assert getattr(busy.stats, k) > 0, k
             assert getattr(idle.stats, k) == 0, k
             assert getattr(busy.stats, k) == getattr(svc.stats, k), k
+
+
+# =============================================================================
+# DROP-DUPLICATES / DIFFERENCE: the three steps as phase spans
+# =============================================================================
+DEDUP_STEPS = ("dedup:keys", "dedup:ids", "dedup:keep")
+
+
+def _trips(s, n=3000, seed=16):
+    rng = np.random.default_rng(seed)
+    return api.from_pydict({
+        "vendor": rng.integers(1, 3, n).tolist(),
+        "pay": rng.choice(["card", "cash", "dispute"], n).tolist(),
+        "lon": (rng.integers(0, 40, n) * 0.25 - 73.5).tolist(),
+        "fare": (rng.random(n) * 50 + 2.5).tolist()}, session=s)
+
+
+def _dedup(s):
+    df = _trips(s)
+    return df[df["fare"] > 10.0].drop_duplicates(["vendor", "pay", "lon"])
+
+
+def _difference(s):
+    df = _trips(s)
+    return df[df["fare"] > 10.0].difference(df[df["fare"] > 30.0])
+
+
+@pytest.mark.parametrize("build", [_dedup, _difference],
+                         ids=["drop_duplicates", "difference"])
+def test_dedup_records_its_steps_under_its_node(build):
+    s = Session(mode=EvalMode.LAZY, trace=True, default_row_parts=3)
+    try:
+        q = build(s)
+        st0 = dataclasses.replace(s.stats)
+        q.collect()
+        st1, tr = s.stats, s.tracer
+        spans = [sp for sp in tr.snapshot() if sp.stmt == tr.last_stmt]
+        totals = tr.counter_totals(tr.last_stmt)
+        assert tr.open_spans() == 0
+    finally:
+        s.close()
+    by_id = {sp.id: sp for sp in spans}
+    node = [sp for sp in spans if sp.cat == "node"
+            and sp.name.endswith(("drop_duplicates", "difference"))]
+    assert len(node) == 1
+    steps = {}
+    for sp in spans:
+        if sp.name in DEDUP_STEPS:
+            assert sp.cat == "phase" and sp.parent == node[0].id, sp.name
+            steps.setdefault(sp.name, []).append(sp)
+    assert sorted(steps) == sorted(DEDUP_STEPS)
+    assert all(len(v) == 1 for v in steps.values())
+    keys, ids, keep = (steps[n][0] for n in DEDUP_STEPS)
+    assert keys.t0 + keys.dur <= ids.t0 and ids.t0 + ids.dur <= keep.t0
+    # the key-extraction pool round (with the absorbed filter) runs in chunks
+    # under dedup:keys, the keep-mask filter in chunks under dedup:keep
+    chunks = [sp for sp in spans if sp.cat == "task"]
+    under = {by_id[by_id[c.parent].parent].name for c in chunks}
+    assert {"dedup:keys", "dedup:keep"} <= under
+    for sp in spans:
+        if sp.name == "stage:select":
+            assert by_id[by_id[by_id[sp.parent].parent].parent] is keys
+    # the span counter deltas still sum exactly to the statement's ExecStats
+    for k in _DELTA_KEYS:
+        assert totals.get(k, 0) == getattr(st1, k) - getattr(st0, k), k
+    assert st1.d2h_copies > st0.d2h_copies
+    assert st1.dedup_blocks > st0.dedup_blocks
+
+
+def test_untraced_dedup_records_nothing():
+    before = trace.recorded_total()
+    s = Session(mode=EvalMode.LAZY, default_row_parts=3)
+    try:
+        assert _dedup(s).collect().nrows > 0
+        assert _difference(s).collect().nrows > 0
+        assert s.stats.dedup_blocks > 0
+    finally:
+        s.close()
+    assert trace.recorded_total() == before
