@@ -201,8 +201,11 @@ class Udf:
     ``deps``: column labels read (None ⇒ all — blocks pushdown through it).
     ``elementwise``: True ⇒ output row i depends only on input row i (legal to
     run per row-block with no cross-partition exchange, and commutes with
-    SELECTION).  Hashing/caching is by ``name`` + ``version``: two Udfs with
-    the same (name, version) are treated as the same function.
+    SELECTION).  ``writes``: column labels the udf sets; declared together
+    with ``deps`` it promises every other column passes through unchanged,
+    so the udf can run over just the columns it reads (None ⇒ unknown).
+    Hashing/caching is by ``name`` + ``version``: two Udfs with the same
+    (name, version) are treated as the same function.
     """
 
     name: str
@@ -211,6 +214,7 @@ class Udf:
     elementwise: bool = True
     out_cols: Optional[tuple] = None     # declared output labels (else inferred)
     version: int = 0
+    writes: Optional[frozenset] = None
 
     @staticmethod
     def wrap(fn: Callable, name: str | None = None, **kw) -> "Udf":

@@ -16,6 +16,7 @@ import itertools
 import os
 from typing import Any, Callable, Iterable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -251,7 +252,8 @@ class DataFrame:
         if isinstance(value, ColumnExpr):
             expr = value._expr
             udf = alg.Udf.wrap(_expr_assign_fn(key, expr), name=f"assign_{key}_{expr!r}",
-                               deps=frozenset(expr.refs()), elementwise=True)
+                               deps=frozenset(expr.refs()), elementwise=True,
+                               writes=frozenset([key]))
             self._node = self._session.statement(alg.Map(self._node, udf))
             return
         # host array/list: eager materialize + splice
@@ -479,15 +481,19 @@ class DataFrame:
 
 
 def _expr_assign_fn(key: str, expr: alg.Expr):
-    from .physical import eval_expr
+    from .physical import eval_expr, null_free
 
     def apply(cdict, frame):
         v, mask = eval_expr(expr, frame)
         dom = (Domain.BOOL if v.dtype == jnp.bool_
                else Domain.INT if jnp.issubdtype(v.dtype, jnp.integer) else Domain.FLOAT)
         out = dict(cdict)
-        out[key] = Column(v, dom, None if bool(to_host(mask.all())) else mask,
-                          None)
+        # inside a traced map run the mask has no value to test: drop it
+        # where the expression cannot make a null
+        if (null_free(expr, frame) if isinstance(mask, jax.core.Tracer)
+                else bool(to_host(mask.all()))):
+            mask = None
+        out[key] = Column(v, dom, mask, None)
         return Frame(list(out.values()), frame.row_labels,
                      labels_from_values(list(out.keys())))
 

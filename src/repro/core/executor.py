@@ -219,7 +219,11 @@ class ExecStats:
       * ``groupby_factorized``    — GROUPBY nodes with keys that took the
                                     general factorization
                                     (``physical._factorize_keys``).  A
-                                    groupby without keys counts neither.
+                                    groupby without keys counts neither;
+      * ``groupby_masked``        — FusedGroupBy nodes whose selections ran
+                                    as a device code mask over uncompacted
+                                    source blocks
+                                    (``physical._masked_groupby``).
     """
 
     evaluated_nodes: int = 0
@@ -262,6 +266,7 @@ class ExecStats:
     compile_ns: int = 0
     groupby_dense: int = 0
     groupby_factorized: int = 0
+    groupby_masked: int = 0
 
     @property
     def blocks_per_dispatch(self) -> float:
@@ -271,7 +276,8 @@ class ExecStats:
 # the ExecStats counters bumped through the node's stats scope: a traced
 # node's span carries its own delta of each, tallied as they are bumped
 SCOPE_COUNTERS = ("d2h_bytes", "d2h_copies", "h2d_bytes", "compiles",
-                  "compile_ns", "groupby_dense", "groupby_factorized")
+                  "compile_ns", "groupby_dense", "groupby_factorized",
+                  "groupby_masked")
 
 _TEE_LOCK = threading.Lock()
 

@@ -171,7 +171,9 @@ def _has_wide_lit(expr: alg.Expr) -> bool:
 
 
 def eval_expr(expr: alg.Expr, frame: Frame) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Vectorized evaluation → (values, valid_mask) device arrays."""
+    """Vectorized evaluation → (values, valid_mask) device arrays.  Inside a
+    traced map run literals broadcast on the device, as in the compiled
+    predicates, rather than entering the program as row-long constants."""
     def getcol(name):
         data, mask, _ = _col_values(frame, name)
         return data, mask
@@ -186,7 +188,25 @@ def eval_expr(expr: alg.Expr, frame: Frame) -> tuple[jnp.ndarray, jnp.ndarray]:
                 return v, c.valid_mask()
         return None
 
-    return _eval_expr_core(expr, getcol, frame.nrows, bin_hook, _host_full)
+    traced = any(isinstance(c.data, jax.core.Tracer) for c in frame.columns)
+    return _eval_expr_core(expr, getcol, frame.nrows, bin_hook,
+                           jnp.full if traced else _host_full)
+
+
+def null_free(expr: alg.Expr, frame: Frame) -> bool:
+    """Whether ``expr`` over ``frame`` is valid in every row, known without
+    reading a value (as inside a trace): its columns hold no mask, and no
+    ``%`` or ``//`` can meet a zero divisor."""
+    if isinstance(expr, alg.ColRef):
+        return frame.col(expr.name).mask is None
+    if isinstance(expr, alg.Lit):
+        return True
+    if isinstance(expr, alg.UnaryExpr):
+        return expr.op in ("isna", "notna") or null_free(expr.operand, frame)
+    if isinstance(expr, alg.BinExpr):
+        return (expr.op not in ("%", "//") and null_free(expr.left, frame)
+                and null_free(expr.right, frame))
+    return False
 
 
 def _lit_to_code(column: Column, value: Any) -> int:
@@ -1217,11 +1237,17 @@ def _groupby_with_codes(blocks: list, keys, aggs, *, dense=None, G: int = 1,
         partials = dispatch_blocks(
             lambda bc: _block_partial(bc[0], bc[1], G, need, presence=False),
             list(zip(blocks, codes_per_block)))
+    return _combine_finalize(partials, need, blocks[0] if blocks else None,
+                             keys, aggs, G, rep_sorted, dense)
+
+
+def _combine_finalize(partials, need, template, keys, aggs, G: int,
+                      rep_sorted=None, dense=None) -> PartitionedFrame:
     with phase("groupby:combine"):
         combined = _combine_partials(partials, need)
     with phase("groupby:finalize"):
-        return _finalize_groupby(combined, blocks[0] if blocks else None,
-                                 keys, aggs, G, rep_sorted, dense)
+        return _finalize_groupby(combined, template, keys, aggs, G,
+                                 rep_sorted, dense)
 
 
 def _finalize_groupby(combined: dict, template: Frame | None, keys, aggs,
@@ -1323,9 +1349,16 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
     where per-block local ranges would recompile per distinct span.  Other
     key sets (coded or multi-key, range > 65536) go to ``_groupby_blocks``
     over the staged blocks — the producer sweep still ran fused, in one pool
-    round instead of one per operator."""
+    round instead of one per operator.
+
+    Where the chain allows it, ``_masked_groupby`` runs instead: one pool
+    round over the source blocks, the selections a device code mask, and
+    nothing staged."""
     pf1 = pf.repartition(col_parts=1)
     blocks = [row[0] for row in pf1.handles]
+    out = _masked_groupby(blocks, stages, keys, aggs, grid)
+    if out is not None:
+        return out
     single_key = len(keys) == 1
 
     def stage_block(block):
@@ -1383,6 +1416,226 @@ def _fused_groupby(pf: PartitionedFrame, stages: Sequence[alg.Stage],
     # has a small rank range, else the general factorization; either needs
     # a global view, but the whole producer sweep still ran as one pool round
     return _groupby_blocks(staged, keys, aggs)
+
+
+# ---- masked producer fusion: the fused groupby's selections as a code mask --
+def _masked_plan(stages: Sequence[alg.Stage], keys, aggs, first: Frame):
+    """(source columns the stages and aggregates read, the keys' source
+    names) when every stage can run over just those columns of an
+    uncompacted block, in one walk over the chain from the schema of the
+    ``first`` block: structured selections, elementwise maps that declare
+    what they read and set (``Udf.deps`` / ``writes``) and read no coded
+    column (its codes need the host code table), projections, and renames
+    that leave every label distinct.  None for any other chain, for a key
+    that is not an INT or coded source column, or where a column read is a
+    64-bit host column (the device would truncate it)."""
+    names = first.col_labels.to_list()
+    dom = dict(zip(names, first.schema))
+    if len(dom) != len(names):
+        return None
+    origin = {n: n for n in names}     # label now → its source column, or
+    reads = set()                      # None for a map's output
+
+    def read(cols) -> bool:
+        if not set(cols) <= origin.keys():
+            return False
+        reads.update(origin[c] for c in cols if origin[c] is not None)
+        return True
+
+    for st in stages:
+        if st.op == "selection":
+            pred = st.params["predicate"]
+            if not isinstance(pred, alg.Expr) or not read(pred.refs()):
+                return None
+        elif st.op == "map":
+            u = st.params["udf"]
+            if (not u.elementwise or u.deps is None or u.writes is None
+                    or not read(u.deps)
+                    or any(origin[d] is not None and dom[origin[d]].is_coded
+                           for d in u.deps)):
+                return None
+            origin.update(dict.fromkeys(u.writes))
+        elif st.op == "projection":
+            cols = list(st.params["cols"])
+            if not set(cols) <= origin.keys() or len(set(cols)) != len(cols):
+                return None
+            origin = {c: origin[c] for c in cols}
+        elif st.op == "rename":
+            m = dict(st.params["mapping"])
+            renamed = {m.get(n, n): o for n, o in origin.items()}
+            if len(renamed) != len(origin):
+                return None
+            origin = renamed
+        else:
+            return None
+    if not read({c for c, _, _ in aggs}) or not set(keys) <= origin.keys():
+        return None
+    src_keys = [origin[k] for k in keys]
+    if (any(k is None or not (dom[k] is Domain.INT or dom[k].is_coded)
+            for k in src_keys)
+            or any(isinstance(d := first.col(c).data, np.ndarray)
+                   and d.dtype.itemsize > 4 for c in reads)):
+        return None
+    return reads, src_keys
+
+
+def _device_column(c: Column) -> Column:
+    """``c`` with its host data and mask sent to the device once."""
+    mask = c.mask if c.mask is None else to_device(c.mask)
+    return Column(to_device(c.data), c.domain, mask, c.dictionary)
+
+
+def _masked_map(frame: Frame, udfs: Sequence[alg.Udf]) -> Frame:
+    """A run of maps over just the columns it reads (``_run_map_stages``:
+    one traced program where the chain traces), with the columns it sets
+    put into ``frame``."""
+    reads = frozenset().union(*(u.deps for u in udfs))
+    writes = frozenset().union(*(u.writes for u in udfs))
+    names = frame.col_labels.to_list()
+    out = _run_map_stages(
+        frame.take_cols([j for j, n in enumerate(names) if n in reads]), udfs)
+    cols = dict(zip(names, frame.columns))
+    cols.update((n, c) for n, c in zip(out.col_labels.to_list(), out.columns)
+                if n in writes)
+    return Frame(list(cols.values()), frame.row_labels,
+                 labels_from_values(list(cols)))
+
+
+def _masked_stages(block: Frame, stages: Sequence[alg.Stage], reads) -> tuple:
+    """A fused groupby's stages over the block's ``reads`` columns on the
+    device, no row removed: (the staged columns, the device keep-mask of
+    the selections, None without one).  The AND of row-local predicates is
+    exact whatever later maps compute in the rows it rejects."""
+    names = block.col_labels.to_list()
+    cur = block.take_cols([j for j, n in enumerate(names) if n in reads])
+    cur = Frame([_device_column(c) for c in cur.columns], cur.row_labels,
+                cur.col_labels)
+    keep, i = None, 0
+    while i < len(stages):
+        op = stages[i].op
+        j = i + 1
+        while op in ("selection", "map") and j < len(stages) and stages[j].op == op:
+            j += 1
+        if op == "selection":
+            k = _selection_keep([st.params["predicate"] for st in stages[i:j]], cur)
+            keep = k if keep is None else keep & k
+        elif op == "map":
+            cur = _masked_map(cur, [st.params["udf"] for st in stages[i:j]])
+        elif op == "rename":
+            cur = _rename_block(cur, dict(stages[i].params["mapping"]))
+        else:                     # a projection, of the columns ``cur`` holds
+            pos = {n: k for k, n in enumerate(cur.col_labels.to_list())}
+            cur = cur.take_cols([pos[c] for c in stages[i].params["cols"]
+                                 if c in pos])
+        i = j
+    return cur, keep
+
+
+@jax.jit
+def _kept_first(keep, codes, datas, masks):
+    """The kept rows first, in their order, and NULL_CODE after them.  The
+    partial program sums each row tile as one matmul, so a kept row must sit
+    where a compacted block would hold it for the sums to round as the
+    unfused plan's do; the shape stays the block's.
+
+    Each kept row moves left by the number of rejected rows before it, one
+    bit of that distance per step, lowest bit first: a static shift and a
+    select per step, where a row gather or a sort costs many times more on a
+    TPU.  The distance never falls from one kept row to the next, so two
+    rows never land on one slot."""
+    n = keep.shape[0]
+    dist = jnp.where(keep, jnp.cumsum(~keep, dtype=jnp.int32), 0)
+    vals = [codes, dist, *datas, *(m for m in masks if m is not None)]
+    held = keep
+    step = 1
+    while step < n:
+        move = held & ((dist & step) != 0)
+        stay = held & ~move
+        held = _shift_left(move, step) | stay
+        vals = [jnp.where(held, jnp.where(stay, v, _shift_left(v, step)),
+                          jnp.zeros((), v.dtype)) for v in vals]
+        dist = vals[1]
+        step *= 2
+    codes, _, *rest = vals
+    it = iter(rest[len(datas):])
+    return (jnp.where(held, codes, NULL_CODE), rest[:len(datas)],
+            [None if m is None else next(it) for m in masks])
+
+
+def _shift_left(v, step: int):
+    """``v`` moved ``step`` rows toward the front, zero-filled at the end."""
+    return jnp.concatenate([v[step:], jnp.zeros((step,), v.dtype)])
+
+
+def _masked_partial_input(frame: Frame, keep, codes, nrows: int) -> tuple:
+    """(columns, group codes) for the partial program, on the device: every
+    row the selections rejected takes NULL_CODE, so it reaches no segment
+    and no presence slot; ``codes`` None (no keys) is segment 0."""
+    codes = jnp.zeros(nrows, jnp.int32) if codes is None else to_device(codes)
+    if keep is None:
+        return frame, codes
+    codes, datas, masks = _kept_first(keep, codes,
+                                      [c.data for c in frame.columns],
+                                      [c.mask for c in frame.columns])
+    cols = [Column(d, c.domain, m, c.dictionary)
+            for c, d, m in zip(frame.columns, datas, masks)]
+    return Frame(cols, RangeLabels(nrows), frame.col_labels), codes
+
+
+def _masked_groupby(blocks: list, stages: Sequence[alg.Stage], keys, aggs,
+                    grid: str | None) -> PartitionedFrame | None:
+    """Masked producer fusion: a fused groupby over uncompacted source
+    blocks.  Each block sends the columns the chain reads to the device
+    once, evaluates its selections there as a keep-mask (the compiled
+    predicate program), runs its maps over those columns, and folds the
+    mask into its group codes, so a rejected row reaches no statistic and
+    no presence slot.  One pool round; no row take, no mask read back,
+    nothing staged into the store.
+
+    None, before any work, where the staged path must run: a chain or a
+    key this path cannot run (``_masked_plan``), keys without a dense range
+    over the source blocks (``_dense_keys``), or a grid the groupby would
+    regroup — regrouping splits rows by count, and only the staged blocks
+    split where the unfused plan does.  Each kept row meets the same rows
+    in the same order in its segment as in the unfused plan."""
+    if not blocks or preferred_row_parts(
+            len(blocks), grid or GRID_PREFS["fused_groupby"]) != len(blocks):
+        return None
+    first = resolve(blocks[0]).induce()
+    plan = _masked_plan(stages, keys, aggs, first)
+    if plan is None:
+        return None
+    reads, src_keys = plan
+    with phase("groupby:resolve"):
+        frames = [first] + [resolve(b).induce() for b in blocks[1:]]
+    dense = None
+    if keys:
+        with phase("groupby:keys"):
+            dense = _dense_keys(frames, src_keys)
+        if dense is None:
+            return None
+        count("groupby_dense")
+    count("groupby_masked")
+    G = math.prod(len(dk.values) for dk in dense) if dense else 1
+    need = _agg_need(aggs)
+    agg_cols = {c for c, _ in need}
+
+    def block_partial(f: Frame) -> dict:
+        codes = None
+        if dense:
+            with phase("groupby:keys"):
+                codes = _dense_codes(f, src_keys, dense)
+        with phase("groupby:stages"):
+            cur, keep = _masked_stages(f, stages, reads)
+            names = cur.col_labels.to_list()
+            cur = cur.take_cols([j for j, n in enumerate(names) if n in agg_cols])
+            cur, codes = _masked_partial_input(cur, keep, codes, f.nrows)
+        return _block_partial(cur, codes, G, need, presence=bool(dense))
+
+    partials = dispatch_blocks(block_partial, frames)
+    if dense:
+        need = need + [_PRESENCE]
+    return _combine_finalize(partials, need, None, keys, aggs, G, dense=dense)
 
 
 # ---- SORT ---------------------------------------------------------------
@@ -1952,7 +2205,8 @@ def _compiled_predicate(expr: alg.Expr, refs: tuple) -> Callable:
         fn = _PRED_JIT.get(key)
         if fn is None:
             def prog(datas, masks):
-                env = {r: (d, m) for r, d, m in zip(refs, datas, masks)}
+                env = {r: (d, jnp.ones(d.shape[0], jnp.bool_) if m is None else m)
+                       for r, d, m in zip(refs, datas, masks)}
                 v, mask = _eval_expr_env(expr, env)
                 return v.astype(jnp.bool_) & mask
             while len(_PRED_JIT) >= _PRED_JIT_MAX:
@@ -1961,8 +2215,10 @@ def _compiled_predicate(expr: alg.Expr, refs: tuple) -> Callable:
     return fn
 
 
-def _fused_selection_mask(preds: Sequence[alg.Expr], frame: Frame) -> np.ndarray:
-    """keep-mask for a run of structured predicates, as ONE device program.
+def _predicate_operands(preds: Sequence[alg.Expr], frame: Frame) -> tuple:
+    """The AND of a run of structured predicates, with the (refs, columns)
+    its compiled program takes, or (combined, None, None) where the
+    interpreted path must evaluate it.
 
     ANDing before filtering is exact: predicates are row-local, so a row
     removed by an earlier selection contributes False to the conjunction
@@ -1972,14 +2228,14 @@ def _fused_selection_mask(preds: Sequence[alg.Expr], frame: Frame) -> np.ndarray
         combined = alg.BinExpr("&", combined, p)
     refs = tuple(sorted(combined.refs(), key=repr))
     if not refs:
-        return _predicate_mask(frame, combined)
+        return combined, None, None
     try:
         cols = [frame.col(r) for r in refs]
     except KeyError:
-        return _predicate_mask(frame, combined)
+        return combined, None, None
     if any(c.domain.is_coded for c in cols):
         # coded columns need host code-table translation → interpreted path
-        return _predicate_mask(frame, combined)
+        return combined, None, None
     if any(c.domain is Domain.INT and c.data.dtype.itemsize > 4
            for c in cols) or _has_wide_lit(combined):
         # wide int64 host columns / out-of-int32 literals would truncate (or
@@ -1987,11 +2243,35 @@ def _fused_selection_mask(preds: Sequence[alg.Expr], frame: Frame) -> np.ndarray
         # path handles them in 64-bit host arithmetic.  dtype check on the
         # array object itself — np.asarray here would device-transfer every
         # predicate column on an accelerator backend.
+        return combined, None, None
+    return combined, refs, cols
+
+
+def _fused_selection_mask(preds: Sequence[alg.Expr], frame: Frame) -> np.ndarray:
+    """keep-mask for a run of structured predicates, as ONE device program,
+    read back to the host for the row take."""
+    combined, refs, cols = _predicate_operands(preds, frame)
+    if cols is None:
         return _predicate_mask(frame, combined)
     fn = _compiled_predicate(combined, refs)
     datas, masks = [c.data for c in cols], [c.valid_mask() for c in cols]
     note_h2d(*datas, *masks)
     return to_host(fn(datas, masks))
+
+
+def _selection_keep(preds: Sequence[alg.Expr], frame: Frame) -> jax.Array:
+    """The same keep-mask left on the device, for a consumer that masks
+    rows instead of taking them; a column without a mask passes None, so
+    no all-valid mask is sent."""
+    combined, refs, cols = _predicate_operands(preds, frame)
+    if cols is None:
+        v, mask = eval_expr(combined, frame)
+        _note_mixed(v, mask)
+        keep = v.astype(jnp.bool_) & mask     # null comparisons → False
+        return to_device(keep) if isinstance(keep, np.ndarray) else keep
+    datas, masks = [c.data for c in cols], [c.mask for c in cols]
+    note_h2d(*datas, *masks)
+    return _compiled_predicate(combined, refs)(datas, masks)
 
 
 # Compiled map-run programs: a run of consecutive elementwise MAP stages

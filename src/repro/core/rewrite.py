@@ -415,13 +415,20 @@ def _fuse_udfs(u1: alg.Udf, u2: alg.Udf) -> alg.Udf:
         cols2 = {n: c for n, c in zip(mid.col_labels.to_list(), mid.columns)}
         return u2.fn(cols2, mid)
 
+    deps, writes = u1.deps, None
+    if None not in (u1.deps, u1.writes, u2.deps, u2.writes):
+        # both declare what they read and set: the pair reads what u1 reads
+        # and what u2 reads that u1 did not set
+        deps = u1.deps | (u2.deps - u1.writes)
+        writes = u1.writes | u2.writes
     return alg.Udf(
         name=f"{u2.name}∘{u1.name}",
         fn=fused,
-        deps=u1.deps,
+        deps=deps,
         elementwise=True,
         out_cols=u2.out_cols,
         version=max(u1.version, u2.version),
+        writes=writes,
     )
 
 
