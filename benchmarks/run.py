@@ -6,7 +6,6 @@
   rewrite       paper §5: transpose-elimination rewrites
   reuse         paper §6.2: session materialization/reuse
   approx        paper §6.1.3: progressive aggregation to ±1%
-  roofline      deliverable (g): table from the dry-run artifacts
   fusion        paper §5: fused row-local pipelines vs per-node evaluation
                 (also writes BENCH_fusion.json)
   blocking_fusion  barrier fusion through GROUPBY/SORT/JOIN/WINDOW
@@ -66,7 +65,7 @@ def main() -> None:
     from . import (bench_approx, bench_blocking_fusion, bench_dedup,
                    bench_faults, bench_fig6, bench_fusion,
                    bench_opportunistic, bench_outofcore, bench_reuse,
-                   bench_rewrite, bench_roofline, bench_scheduling,
+                   bench_rewrite, bench_scheduling,
                    bench_service, bench_shuffle, bench_trace)
     suites = {
         "fig6": bench_fig6.run,
@@ -74,7 +73,6 @@ def main() -> None:
         "rewrite": bench_rewrite.run,
         "reuse": bench_reuse.run,
         "approx": bench_approx.run,
-        "roofline": bench_roofline.run,
         "fusion": bench_fusion.run,
         "blocking_fusion": bench_blocking_fusion.run,
         "scheduling": bench_scheduling.run,

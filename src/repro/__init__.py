@@ -1,7 +1,6 @@
 """repro: *Towards Scalable Dataframe Systems* (Petersohn et al., 2020) on
-JAX/TPU — a Modin-style partitioned dataframe system (core/), Pallas kernels
-for its hot operators (kernels/), and the LM training/serving substrate that
-the assigned architectures × shapes run on (models/, train/, serve/,
-launch/), with the dataframe system as the data pipeline (data/).
+JAX/TPU — a Modin-style partitioned dataframe engine (core/), Pallas kernels
+for its hot operators (kernels/), and synthetic taxi-shaped data for its
+tests and examples (data/).
 """
 __version__ = "0.1.0"

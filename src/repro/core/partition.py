@@ -15,9 +15,7 @@ work is a jit-compiled function dispatched onto a shared thread pool (XLA
 releases the GIL while executing, so partitions genuinely run in parallel
 across cores).  Dispatch goes through the scheduling layer
 (``schedule.dispatch_blocks``), which coalesces several blocks into one pool
-task when partitions ≫ workers.  On the TPU mesh the same grid maps onto
-(data, model) axes via shard_map — see ``physical.py`` and
-``launch/dryrun.py``.
+task when partitions ≫ workers.
 
 The headline trick (paper §4.2 "Supporting billions of columns"): TRANSPOSE is
 a *grid* transpose — each block is transposed locally (a Pallas kernel on

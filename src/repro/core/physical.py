@@ -23,10 +23,6 @@ Each logical operator picks a partitioning scheme per the paper's §4.2 table:
                                   blockwise keep-mask filters — the input is
                                   never concatenated (no ``to_frame()``).
 
-The same operator bodies double as the shard_map shard-level programs for the
-TPU mesh (see ``launch/dryrun.py`` — the pipeline dry-run lowers MAP/GROUPBY/
-WINDOW over the production mesh with psums standing in for the combines).
-
 Fused pipelines (paper §5 "Pipelining")
 ---------------------------------------
 ``FUSED_PIPELINE`` executes a whole chain of row-local operators (elementwise

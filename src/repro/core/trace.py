@@ -50,10 +50,9 @@ exit.  ``Session(trace=True)`` gives one session its own tracer (bounded
 ring, independent of the process one), resolved through the session's
 ``config.SessionConfig`` exactly like the store / fault / retry knobs.
 
-The metrics half: :class:`Metrics` is the one named-counter/gauge registry
-shape shared by the serve tier (``serve.engine.ServeEngine.metrics``) and the
-core tier (:func:`stats_metrics` projects an ``ExecStats`` into it), so both
-export the same ``{"name": ..., "metrics": {...}}`` dict.
+The metrics half: :class:`Metrics` is a named-counter/gauge registry with one
+export shape, ``{"name": ..., "metrics": {...}}``; :func:`stats_metrics`
+projects an ``ExecStats`` into it.
 """
 from __future__ import annotations
 
@@ -489,12 +488,12 @@ def export(path: str) -> str | None:
 
 
 # =============================================================================
-# the metrics registry (shared export shape: serve tier + core tier)
+# the metrics registry
 # =============================================================================
 class Metrics:
     """Named counters/gauges behind one export shape.  Dict-style access
-    (``m["steps"] += 1``) keeps existing serve-tier call sites working;
-    missing names read as 0 so counters need no pre-registration."""
+    (``m["steps"] += 1``) works; missing names read as 0 so counters need no
+    pre-registration."""
 
     __slots__ = ("name", "_vals", "_lock")
 
@@ -536,8 +535,8 @@ class Metrics:
             return dict(self._vals)
 
     def export(self) -> dict:
-        """The ONE export shape both tiers share (serve engine metrics and
-        ``ExecStats`` projections serialize identically)."""
+        """The ONE export shape (``ExecStats`` projections serialize the same
+        way as any other registry)."""
         return {"name": self.name, "metrics": self.as_dict()}
 
     def __repr__(self) -> str:

@@ -1,4 +1,3 @@
-"""Data pipeline: the dataframe system feeding training (paper → practice)."""
-from .pipeline import DataPipeline, PipelineConfig  # noqa: F401
-from .synthetic import numeric_matrix_frame, synthetic_corpus, taxi_like_frame  # noqa: F401
-from .tokenizer import HashTokenizer  # noqa: F401
+"""Synthetic tabular data shaped like the paper's NYC-taxicab benchmark, for
+the engine's tests, examples and CPU benchmark suites."""
+from .synthetic import numeric_matrix_frame, taxi_like_frame  # noqa: F401

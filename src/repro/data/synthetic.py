@@ -1,30 +1,11 @@
-"""Synthetic data generators: a toy text corpus (for the end-to-end training
-examples) and tabular data shaped like the paper's NYC-taxicab benchmark
-(for the dataframe benchmarks — Fig. 6 uses taxi trips replicated 1–11×)."""
+"""Synthetic tabular data shaped like the paper's NYC-taxicab benchmark (for
+the dataframe benchmarks — Fig. 6 uses taxi trips replicated 1–11×)."""
 from __future__ import annotations
 
 import numpy as np
 
 from ..core.dtypes import Domain
 from ..core.frame import Frame
-
-_WORDS = (
-    "the of and a to in is you that it he was for on are as with his they I "
-    "at be this have from or one had by word but not what all were we when "
-    "your can said there use an each which she do how their if will up other "
-    "about out many then them these so some her would make like him into time "
-    "has look two more write go see number no way could people my than first "
-    "water been call who oil its now find long down day did get come made may"
-).split()
-
-
-def synthetic_corpus(n_docs: int, seed: int = 0, mean_len: int = 64) -> list[str]:
-    rng = np.random.default_rng(seed)
-    docs = []
-    for _ in range(n_docs):
-        n = max(4, int(rng.poisson(mean_len)))
-        docs.append(" ".join(rng.choice(_WORDS, size=n)))
-    return docs
 
 
 def taxi_like_frame(n_rows: int, seed: int = 0, n_float_cols: int = 6) -> Frame:

@@ -1,15 +1,9 @@
-"""Pallas TPU kernels for the perf-critical compute layers.
-
-Data-system kernels (the paper's Fig. 6 hot-spots):
+"""Pallas TPU kernels for the dataframe engine's hot operators (the paper's
+Fig. 6 hot-spots):
   block_transpose — TRANSPOSE's per-block tile transpose
   segment_reduce  — GROUPBY(n) aggregation as MXU one-hot matmul
   window_scan     — WINDOW cumulative ops as a blocked carry scan
   onehot_encode   — get_dummies (§2 A1)
-
-LM-substrate kernels:
-  flash_attention — fused online-softmax attention (train / prefill)
-  decode_attention— single-token GQA attention over a KV cache
-  linear_scan     — h_t = a_t·h_{t-1} + b_t (RG-LRU / RWKV6 primitive)
 
 ``ops`` is the public dispatching surface; ``ref`` holds pure-jnp oracles.
 """

@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from ..core.transfer import note_h2d
 from . import ref
 from .block_transpose import block_transpose as _pallas_transpose
-from .decode_attention import decode_attention as _pallas_decode
-from .flash_attention import flash_attention as _pallas_flash
-from .linear_scan import linear_scan as _pallas_linscan
 from .onehot_encode import onehot_encode as _pallas_onehot
 from .segment_reduce import segment_reduce as _pallas_segred
 from .window_scan import window_scan as _pallas_winscan
@@ -35,8 +32,7 @@ from ._util import narrow_from_kernel, widen_for_kernel
 
 __all__ = [
     "use_pallas", "transpose", "segment_reduce", "segment_reduce_multi",
-    "window_scan", "linear_scan", "onehot_encode", "flash_attention",
-    "decode_attention",
+    "window_scan", "onehot_encode",
 ]
 
 
@@ -129,26 +125,8 @@ def window_scan(x, op: str = "cumsum"):
     return ref.window_scan(x.astype(jnp.float32), op)
 
 
-def linear_scan(a, b):
-    if use_pallas():
-        return _pallas_linscan(a, b)
-    return ref.linear_scan(a.astype(jnp.float32), b.astype(jnp.float32))
-
-
 def onehot_encode(codes, num_classes: int):
     note_h2d(codes)
     if use_pallas():
         return _pallas_onehot(codes, num_classes)
     return ref.onehot_encode(codes, num_classes)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, scale=None, window=None):
-    if use_pallas():
-        return _pallas_flash(q, k, v, causal=causal, scale=scale, window=window)
-    return ref.flash_attention(q, k, v, causal=causal, scale=scale, window=window)
-
-
-def decode_attention(q, k_cache, v_cache, length, *, scale=None):
-    if use_pallas():
-        return _pallas_decode(q, k_cache, v_cache, length, scale=scale)
-    return ref.decode_attention(q, k_cache, v_cache, length, scale=scale)

@@ -318,7 +318,7 @@ def test_ring_buffer_bounds_retention():
 
 
 # =============================================================================
-# metrics registry (shared shape: core ExecStats + serve engine)
+# metrics registry (one export shape: Metrics and ExecStats projections)
 # =============================================================================
 def test_metrics_registry_shape_and_serve_unification():
     m = trace.Metrics("m", steps=0)
@@ -335,9 +335,6 @@ def test_metrics_registry_shape_and_serve_unification():
     proj = trace.stats_metrics(st)
     assert set(proj.export()) == set(exp)        # ONE export shape
     assert proj["evaluated_nodes"] == 5
-
-    from repro.serve import engine as serve_engine
-    assert serve_engine.Metrics is trace.Metrics  # serve tier unified
 
 
 # =============================================================================
