@@ -223,7 +223,13 @@ class ExecStats:
       * ``groupby_masked``        — FusedGroupBy nodes whose selections ran
                                     as a device code mask over uncompacted
                                     source blocks
-                                    (``physical._masked_groupby``).
+                                    (``physical._masked_groupby``);
+      * ``resident_cols``         — columns a masked groupby's blocks read
+                                    that were already on the device, one per
+                                    column per block (a registered device
+                                    table's blocks stay resident,
+                                    ``Frame.split_rows``): they send nothing
+                                    and add nothing to ``h2d_bytes``.
     """
 
     evaluated_nodes: int = 0
@@ -267,6 +273,7 @@ class ExecStats:
     groupby_dense: int = 0
     groupby_factorized: int = 0
     groupby_masked: int = 0
+    resident_cols: int = 0
 
     @property
     def blocks_per_dispatch(self) -> float:
@@ -277,7 +284,7 @@ class ExecStats:
 # node's span carries its own delta of each, tallied as they are bumped
 SCOPE_COUNTERS = ("d2h_bytes", "d2h_copies", "h2d_bytes", "compiles",
                   "compile_ns", "groupby_dense", "groupby_factorized",
-                  "groupby_masked")
+                  "groupby_masked", "resident_cols")
 
 _TEE_LOCK = threading.Lock()
 

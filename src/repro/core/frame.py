@@ -18,8 +18,10 @@ Representation (DESIGN.md §3 — hardware adaptation):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,10 +77,12 @@ class Column:
         return np.ones(self.data.shape[0], dtype=np.bool_)
 
     def value_at(self, i: int):
-        """Decode a single position (host) without materializing the column."""
-        if self.mask is not None and not bool(to_host(self.mask[i])):
+        """Decode a single position from the column's host copy (a device
+        column's is fetched once and cached, ``transfer.to_host``), so a
+        resident block costs no device program per position."""
+        if self.mask is not None and not bool(to_host(self.mask)[i]):
             return None
-        v = to_host(self.data[i])
+        v = to_host(self.data)[i]
         if self.domain.is_coded:
             code = int(v)
             return self.dictionary[code] if 0 <= code < len(self.dictionary) else None
@@ -93,9 +97,11 @@ class Column:
         on every backend.  Take lengths are data-dependent, and on a device
         each new length compiles a new XLA program: a sample-sort's per-block
         gathers and concats build one program per piece, and a 1M-row sort
-        on a TPU v5e did not finish in nine minutes.  The host copy of a
-        device array is cached on the array, so a block is fetched once
-        (and counted once, ``transfer.to_host``)."""
+        on a TPU v5e did not finish in nine minutes.  A device column, such
+        as a resident source block (``Frame.split_rows``), has its host copy
+        fetched on the first take and cached on the array: every later take
+        of that block, in any statement, reads the cached copy and moves
+        nothing (counted once, ``transfer.to_host``)."""
         idx_np = to_host(idx)
         return Column(
             to_host(self.data)[idx_np], self.domain,
@@ -251,15 +257,43 @@ class Frame:
     # ------------------------------------------------------------------
     def take_rows(self, idx) -> "Frame":
         idx_np = to_host(idx)
+        return self._with_rows([c.take(idx_np) for c in self.columns], idx_np)
+
+    def _with_rows(self, columns: list[Column], idx_np: np.ndarray) -> "Frame":
+        """``columns`` (rows ``idx_np`` of this frame's) with the row labels
+        and row schema of those rows."""
         rd = None
         if self.row_domains is not None and len(self.row_domains) == self.nrows:
             rd = tuple(self.row_domains[int(i)] for i in idx_np)
-        return Frame(
-            [c.take(idx_np) for c in self.columns],
-            self.row_labels.take(idx_np),
-            self.col_labels,
-            rd,
-        )
+        return Frame(columns, self.row_labels.take(idx_np), self.col_labels, rd)
+
+    def split_rows(self, sizes: Sequence[int]) -> list["Frame"]:
+        """The frame cut into consecutive row blocks of ``sizes`` rows.
+
+        A device array (a column's data or mask) stays on the device: it is
+        cut by one program per shape, dtype and ``sizes``, never one slice
+        per block (an eager slice compiles once per offset), and a single
+        block keeps the array itself.  So the blocks of a registered device
+        table are resident, and a statement sends none of them again.  A
+        host array is cut as ``take_rows`` cuts it.  The placement follows
+        the registered column; the values, and so every result, are the
+        same either way."""
+        sizes = tuple(int(n) for n in sizes)
+        edges = np.cumsum((0,) + sizes)
+        rows = [np.arange(r0, r1) for r0, r1 in zip(edges[:-1], edges[1:])]
+
+        def pieces(a) -> list:
+            if a is None:
+                return [None] * len(rows)
+            if isinstance(a, jax.Array):
+                return [a] if len(rows) == 1 else _split_device(a, sizes)
+            h = to_host(a)
+            return [h[idx] for idx in rows]
+
+        cols = [(c, pieces(c.data), pieces(c.mask)) for c in self.columns]
+        return [self._with_rows([Column(data[b], c.domain, mask[b], c.dictionary)
+                                 for c, data, mask in cols], idx)
+                for b, idx in enumerate(rows)]
 
     def filter_rows(self, keep: np.ndarray) -> "Frame":
         idx = np.nonzero(to_host(keep))[0]
@@ -369,6 +403,12 @@ class Frame:
             if c.mask is not None:
                 total += c.mask.size
         return total
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _split_device(x, sizes: tuple[int, ...]):
+    """``x`` cut into consecutive pieces of ``sizes`` rows, on the device."""
+    return jnp.split(x, np.cumsum(sizes[:-1]).tolist())
 
 
 def _concat_arrays(a, b):

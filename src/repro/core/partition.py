@@ -230,13 +230,13 @@ class PartitionedFrame:
     # ------------------------------------------------------------------
     @staticmethod
     def from_frame(frame: Frame, row_parts: int = 1, col_parts: int = 1) -> "PartitionedFrame":
+        """``frame`` as a grid of contiguous row blocks and column blocks.
+        Device columns stay on the device (``Frame.split_rows``), so a
+        registered device table's blocks are resident."""
         row_sz = _split_sizes(frame.nrows, row_parts)
         col_sz = _split_sizes(frame.ncols, col_parts)
         grid: list[list[Frame]] = []
-        r0 = 0
-        for rs in row_sz:
-            row_block = frame.take_rows(np.arange(r0, r0 + rs))
-            r0 += rs
+        for row_block in frame.split_rows(row_sz):
             row_cells: list[Frame] = []
             c0 = 0
             for cs in col_sz:

@@ -1476,7 +1476,10 @@ def _masked_plan(stages: Sequence[alg.Stage], keys, aggs, first: Frame):
 
 
 def _device_column(c: Column) -> Column:
-    """``c`` with its host data and mask sent to the device once."""
+    """``c`` on the device: a resident column as it is (counted in
+    ``resident_cols``), a host column's data and mask sent once."""
+    if isinstance(c.data, jax.Array):
+        count("resident_cols")
     mask = c.mask if c.mask is None else to_device(c.mask)
     return Column(to_device(c.data), c.domain, mask, c.dictionary)
 

@@ -3,9 +3,12 @@ device that the engine makes goes through these helpers, which bump
 ``ExecStats.d2h_bytes`` / ``d2h_copies`` / ``h2d_bytes`` of the plan node
 being evaluated (``schedule.count``; nothing outside a node).
 
-Row takes, concats and key work run as host numpy (``frame.Column.take``),
-so a statement moves its columns to the host and the filtered ones back.
-These counters say how much, per statement and per node span.
+Row takes, concats and key work run as host numpy (``frame.Column.take``).
+A registered device table's blocks stay on the device
+(``frame.Frame.split_rows``): device programs read them where they are,
+and a row take or key read fetches each block column's host copy once and
+caches it on the array, so only the first statement to read a column moves
+it.  These counters say how much moves, per statement and per node span.
 """
 from __future__ import annotations
 
